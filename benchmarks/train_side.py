@@ -161,7 +161,7 @@ def _measure_schedules_inprocess(schedules, steps, batch, seq, microbatches,
     ``priority+partition`` step at each candidate micro-op size (the
     measured, smoke-scale Fig. 15) and runs the main ablation at the
     measured minimum.  Returns (rows, sweep_rows, partition_bytes)."""
-    from repro.launch.mesh import mesh_context
+    from repro.launch.mesh import make_mesh, mesh_context
     from repro.optim import reduce as reduce_mod
 
     n = jax.device_count()
@@ -169,7 +169,7 @@ def _measure_schedules_inprocess(schedules, steps, batch, seq, microbatches,
     # are enough devices for both axes (n>=4 -> a2a AND reduce contend)
     ep = 2 if n % 2 == 0 and n >= 4 else 1
     dp = max(n // ep, 1)
-    mesh = jax.make_mesh((dp, ep), ("data", "model"))
+    mesh = make_mesh((dp, ep), ("data", "model"))
     cfg = GPT2_MOE.smoke()
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=seq,
                     global_batch=batch)
@@ -250,17 +250,18 @@ def _measure_overlap_inprocess(variants, chunk_counts, steps, batch, seq,
     (resolve_chunk_count; no silent caps)."""
     import dataclasses
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import microop
     from repro.core import moe as moe_mod
     from repro.core.gating import capacity
+    from repro.launch.mesh import make_mesh
 
     n = jax.device_count()
     ep = 2 if n % 2 == 0 and n >= 4 else 1
     dp = max(n // ep, 1)
-    mesh = jax.make_mesh((dp, ep), ("data", "model"))
+    mesh = make_mesh((dp, ep), ("data", "model"))
     cfg = GPT2_MOE.smoke()
     d, e, k = cfg.d_model, cfg.moe.n_experts, cfg.moe.top_k
     f = cfg.moe.d_ff or cfg.d_ff
@@ -306,7 +307,7 @@ def _measure_overlap_inprocess(variants, chunk_counts, steps, batch, seq,
             return back[0] if len(back) == 1 else jnp.concatenate(back,
                                                                   axis=1)
         fn = shard_map(body, mesh=mesh, in_specs=(P(None, None, None),),
-                       out_specs=P(None, None, None), check_rep=False)
+                       out_specs=P(None, None, None), check_vma=False)
         return timed(jax.jit(fn), buf)
 
     a2a_us = {nc: a2a_time(nc) for nc in chunk_counts}
@@ -329,6 +330,26 @@ def _measure_overlap_inprocess(variants, chunk_counts, steps, batch, seq,
     return rows
 
 
+def _forced_host_worker_env(device_count: int) -> dict:
+    """Environment for a forced-host-device CPU worker process.
+
+    Refuses on a TPU backend: this process then holds the chip, so a child
+    that also reaches for it would fail or hang — and forced host devices
+    are a CPU stand-in that says nothing about the chip."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "the forced-host-device benchmark workers run only on a CPU "
+            "backend: this process holds the TPU, and a child process "
+            "cannot share it (run with JAX_PLATFORMS=cpu)")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        f" --xla_force_host_platform_device_count"
+                        f"={device_count}").strip()
+    env["PYTHONPATH"] = os.pathsep.join([os.path.join(repo, "src"), repo])
+    return env
+
+
 def overlap_rows_subprocess(device_count: int = 4, steps: int = 5,
                             batch: int = 4, seq: int = 32,
                             variants=OVERLAP_VARIANTS,
@@ -337,11 +358,7 @@ def overlap_rows_subprocess(device_count: int = 4, steps: int = 5,
     """Spawn the forced-device worker for the overlap microbench only and
     return the parsed rows (shared by the infer-side benchmark)."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count"
-                        f"={device_count}").strip()
-    env["PYTHONPATH"] = os.pathsep.join([os.path.join(repo, "src"), repo])
+    env = _forced_host_worker_env(device_count)
     cmd = [sys.executable, "-m", "benchmarks.train_side",
            "--steps", str(steps), "--batch", str(batch), "--seq", str(seq),
            "--overlap-variants", ",".join(variants),
@@ -399,11 +416,7 @@ def measured_schedule_ablation(device_count: int = 4, steps: int = 5,
     import json
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count"
-                        f"={device_count}").strip()
-    env["PYTHONPATH"] = os.pathsep.join([os.path.join(repo, "src"), repo])
+    env = _forced_host_worker_env(device_count)
     cmd = [sys.executable, "-m", "benchmarks.train_side",
            "--schedules", ",".join(schedules), "--steps", str(steps),
            "--batch", str(batch), "--seq", str(seq),

@@ -17,8 +17,11 @@ Hybrid AST + call-site registry:
   - ``vmem-over-budget``: static per-grid-step footprint (resident blocks
     once, streamed blocks twice for the double-buffered pipeline, plus
     scratch) exceeding the per-core budget;
-  - ``misaligned-block``: block dims that are neither 1, nor the full array
-    extent, nor a multiple of the lane/sublane tile for their dtype;
+  - ``misaligned-block``: a block's last dim that is neither the full
+    array extent nor a multiple of the 128-lane tile, or its second-to-last
+    dim that is neither the full extent nor a multiple of the dtype's
+    sublane tile (a dim of 1 passes only at full extent, or outside the
+    last two dims) — the rule Mosaic enforces when it compiles the call;
   - ``untiled-block``: blocks covering the full extent of a dim that scales
     with tokens (T), dispatch rows (R = E*C) or a contraction (K) — the
     PR-4 VMEM ceilings surfaced here until the dispatch/combine/matmul
@@ -338,16 +341,17 @@ def _eval_weighted_route(c: ShapeCase):
 
 
 def _eval_topk_positions(c: ShapeCase):
-    bt, t_pad = block_and_pad(c.T, 1024)
+    bt, t_pad = block_and_pad(c.T, 512)
     e_pad = pad_to(max(c.E, 1), LANE)
+    # grid (phase, T tiles); the position block's index is (i * phase, 0)
     return [SiteEval(
-        "topk_gating.py", "topk_positions", c.name, (c.K, t_pad // bt),
+        "topk_gating.py", "topk_positions", c.name, (2, t_pad // bt),
         inputs=[
-            Block("idx", (bt, 1), "int32", (grid_dim(1), grid_dim(0)),
+            Block("idx", (bt, c.K), "int32", (grid_dim(1), CONST),
                   (t_pad, c.K)),
         ],
         outputs=[
-            Block("pos", (bt, 1), "int32", (grid_dim(1), grid_dim(0)),
+            Block("pos", (bt, c.K), "int32", (EXPR, CONST),
                   (t_pad, c.K)),
             Block("cnt", (SUBLANE, e_pad), "int32", (CONST, CONST),
                   (SUBLANE, e_pad)),
@@ -455,8 +459,8 @@ def _eval_rwkv6(_c=None):
            for n in ("r", "k", "v", "w")]
     return [SiteEval(
         "rwkv6.py", "rwkv6_wkv", "canonical", (b * h, t // chunk),
-        inputs=blk + [Block("u", (1, hd), "float32",
-                            (grid_dim(0), CONST), (b * h, hd))],
+        inputs=blk + [Block("u", (1, 1, hd), "float32",
+                            (grid_dim(0), CONST, CONST), (b * h, 1, hd))],
         outputs=[Block("out", (1, chunk, hd), "float32", tile,
                        (b * h, t, hd))],
         scratch=[((hd, hd), "float32")])]
@@ -470,14 +474,14 @@ def _eval_ssd(_c=None):
         "ssd.py", "ssd_scan", "canonical", (bsz * h, t // q),
         inputs=[
             Block("x", (1, q, p), "float32", tile, (bsz * h, t, p)),
-            Block("dt", (1, q), "float32", (grid_dim(0), grid_dim(1)),
-                  (bsz * h, t)),
-            Block("a_log", (1, 1), "float32", (grid_dim(0), CONST),
-                  (bsz * h, 1)),
+            Block("dt", (1, 1, q), "float32",
+                  (grid_dim(0), CONST, grid_dim(1)), (bsz * h, 1, t)),
+            Block("a_log", (1, 1, 1), "float32", (grid_dim(0), CONST, CONST),
+                  (bsz * h, 1, 1)),
             Block("b", (1, q, n), "float32", tile, (bsz * h, t, n)),
             Block("c", (1, q, n), "float32", tile, (bsz * h, t, n)),
-            Block("d_skip", (1, 1), "float32", (grid_dim(0), CONST),
-                  (bsz * h, 1)),
+            Block("d_skip", (1, 1, 1), "float32",
+                  (grid_dim(0), CONST, CONST), (bsz * h, 1, 1)),
         ],
         outputs=[Block("out", (1, q, p), "float32", tile, (bsz * h, t, p))],
         scratch=[((p, n), "float32")])]
@@ -535,7 +539,7 @@ def check_alignment(ev: SiteEval, module: str) -> list:
         for dim, need in needs:
             size = int(b.shape[dim])
             full = b.array_shape and int(b.array_shape[dim]) == size
-            if size == 1 or full or size % need == 0:
+            if full or size % need == 0:
                 continue
             out.append(Finding(
                 "misaligned-block", module, ev.qualname,

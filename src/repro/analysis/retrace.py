@@ -62,7 +62,7 @@ def no_retrace(where: str = "steady-state", *, allow: int = 0,
         return
     with cm as count:
         yield report
-    report.count = int(count[0])
+    report.count = int(count())
     if strict and not report.ok:
         raise RetraceError(
             f"{report.count} new jit trace(s) during {where} "

@@ -33,7 +33,7 @@ def axis_sizes(mesh) -> dict:
     """{axis name: size} for ``mesh`` (empty for None)."""
     if mesh is None:
         return {}
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(mesh.shape)
 
 
 def dp_axes(mesh) -> tuple:

@@ -90,6 +90,14 @@ def top_k_gating(logits: jax.Array, top_k: int, cap: int,
     return gating_from_topk(expert_idx, gate_w, probs, cap, aux_loss_weight)
 
 
+def router_logits(x: jax.Array, router: jax.Array) -> jax.Array:
+    """``x @ router`` accumulated and returned in f32.  Every gating path
+    (XLA, the fused kernel and its oracle, the server's popularity gate)
+    ranks experts on these unrounded logits: rounded to bf16, two experts
+    can tie, and the backends break such a tie differently."""
+    return jnp.dot(x, router, preferred_element_type=jnp.float32)
+
+
 def router_top_k_gating(x: jax.Array, router: jax.Array, top_k: int,
                         cap: int, aux_loss_weight: float = 0.01, *,
                         compute_backend: str = "xla") -> GatingResult:
@@ -101,7 +109,8 @@ def router_top_k_gating(x: jax.Array, router: jax.Array, top_k: int,
     two backends produce identical GatingResults.
     """
     if compute_backend != "pallas":
-        return top_k_gating(x @ router, top_k, cap, aux_loss_weight)
+        return top_k_gating(router_logits(x, router), top_k, cap,
+                            aux_loss_weight)
     from repro.kernels import ops as kernel_ops
     idx, gate_w, probs = kernel_ops.topk_gating_op(x, router, top_k,
                                                    use_pallas=True)

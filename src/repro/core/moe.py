@@ -12,9 +12,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.core import axes
@@ -32,7 +31,8 @@ def default_mesh():
     collectives) also runs on a bare CPU — used by smoke tests."""
     global _DEFAULT_MESH
     if _DEFAULT_MESH is None:
-        _DEFAULT_MESH = jax.make_mesh((1, 1), (axes.DATA, axes.MODEL))
+        from repro.launch.mesh import make_mesh
+        _DEFAULT_MESH = make_mesh((1, 1), (axes.DATA, axes.MODEL))
     return _DEFAULT_MESH
 
 
@@ -231,6 +231,6 @@ def moe_layer(mesh, x, params: MoEParams, cfg: MoEConfig, *,
         wrapped, mesh=mesh,
         in_specs=(bspec, P(None, None), wspec_i, wu_spec, wspec_o) + sc_specs,
         out_specs=(bspec, P(), flat_spec, flat_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params.router, params.wi, wu, params.wo, *sc_in)
     return MoEOutput(y, aux, eidx, probs, tok)

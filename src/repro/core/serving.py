@@ -29,9 +29,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.core import axes
@@ -443,7 +442,7 @@ def serve_moe_layer(mesh, x, params: MoEParams, cfg: MoEConfig,
                   wspec, P(None, None), P(None, None), P(None),
                   P(None, None)),
         out_specs=(bspec, bspec, bspec),
-        check_rep=False,
+        check_vma=False,
     )(x, params.router, params.wi, wu, params.wo,
       plan.slot_expert, plan.replica_of, plan.n_replicas, rweight)
     return y, eidx, probs

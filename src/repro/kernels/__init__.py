@@ -9,8 +9,10 @@ oracles (ref.py) and backend-dispatching wrappers (ops.py):
   rwkv6            chunked WKV recurrence (rwkv6-1.6b)
   ssd              Mamba2 chunk scan (zamba2-1.2b)
 
-Kernels compile natively on TPU; this container validates them with
-``interpret=True`` (kernel bodies executed on CPU) against ref.py.
+Kernels compile natively (Mosaic) on a TPU backend.  On the CPU test
+backend they run with ``interpret=True`` (kernel bodies executed on the
+host) against ref.py, and tests/test_tpu_compile.py compiles the main-path
+ones ahead of time for a described v5e chip.
 """
 from repro.kernels.ops import (dispatch_combine_op, flash_attention_op,
                                grouped_ffn_op, on_tpu, resolve_backend,

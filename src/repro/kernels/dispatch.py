@@ -10,7 +10,8 @@ one O(E*C) scatter of ids, no feature data), and:
 
   * ``dispatch_rows``  — grid over (output-row tile, source tile); each
     output tile is revisited across the streamed source tiles, gathering the
-    rows that live in the current tile and accumulating (rows outside the
+    rows that live in the current tile as a 0/1 one-hot matmul on the MXU
+    (Mosaic has no in-kernel row gather) and accumulating (rows outside the
     tile contribute exactly 0.0, so the result is bitwise the single-pass
     gather).  An optional per-row scale also serves the combine-backward,
     where the scattered rows are gate-weighted cotangents.
@@ -40,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.tiling import (VMEM_BUDGET_BYTES, block_and_pad,
-                                  default_interpret)
+                                  default_interpret, lane_column)
 
 
 def dispatch_vmem_bytes(block_rows: int, block_src: int, d: int,
@@ -96,6 +97,16 @@ def invert_slots(rows, n_rows: int):
     return jnp.where(src >= 0, src // k, -1), jnp.where(src >= 0, src % k, -1)
 
 
+def _gather_dot(onehot, tile):
+    """Row gather as a 0/1 matmul on the MXU: ``onehot`` [n, b] selects rows
+    of ``tile`` [b, d].  f32 accumulation, and full f32 passes for an f32
+    tile, so each selected row comes out exactly."""
+    prec = (jax.lax.Precision.HIGHEST if tile.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    return jnp.dot(onehot.astype(tile.dtype), tile, precision=prec,
+                   preferred_element_type=jnp.float32)
+
+
 def _dispatch_kernel(src_ref, scale_ref, x_ref, o_ref, *, block_src: int):
     # source tiles stream along grid dim 1; the output tile is revisited,
     # zero-initialized on the first source tile and accumulated in fp32.
@@ -107,12 +118,12 @@ def _dispatch_kernel(src_ref, scale_ref, x_ref, o_ref, *, block_src: int):
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    idx = src_ref[...][:, 0]                            # [br] global token
+    idx = src_ref[...]                                  # [br, 1] global token
     local = idx - j * block_src
     inside = (idx >= 0) & (local >= 0) & (local < block_src)
-    rows = jnp.take(x_ref[...], jnp.clip(local, 0, block_src - 1), axis=0)
-    s = jnp.where(inside, scale_ref[...][:, 0], 0.0)    # [br] f32
-    o_ref[...] += rows.astype(jnp.float32) * s[:, None]
+    col = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], block_src), 1)
+    rows = _gather_dot((local == col) & inside, x_ref[...])   # [br, d] f32
+    o_ref[...] += rows * scale_ref[...]
 
 
 def dispatch_rows(x, src_tok, scale=None, *, block_rows: int = 1024,
@@ -159,7 +170,8 @@ def dispatch_rows(x, src_tok, scale=None, *, block_rows: int = 1024,
     return out[:r].astype(x.dtype)
 
 
-def _combine_kernel(idx_ref, w_ref, buf_ref, o_ref, *, block_rows: int):
+def _combine_kernel(idx_ref, w_ref, buf_ref, o_ref, *, k: int,
+                    block_rows: int):
     # slot-buffer tiles stream along grid dim 1; each (token, choice) hits
     # exactly one tile (others add 0.0) and fp32 addition is commutative,
     # so the accumulated weighted sum equals the one-pass reduction bitwise.
@@ -170,12 +182,21 @@ def _combine_kernel(idx_ref, w_ref, buf_ref, o_ref, *, block_rows: int):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     idx = idx_ref[...]                                  # [bt, k]
-    local = idx - j * block_rows
-    inside = (idx >= 0) & (local >= 0) & (local < block_rows)
-    vals = jnp.take(buf_ref[...], jnp.clip(local, 0, block_rows - 1),
-                    axis=0)                             # [bt, k, d]
-    w = jnp.where(inside, w_ref[...], 0.0)              # [bt, k] f32
-    o_ref[...] += jnp.sum(vals.astype(jnp.float32) * w[..., None], axis=1)
+    w = w_ref[...]                                      # [bt, k] f32
+    lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], block_rows), 1)
+    buf = buf_ref[...]
+    acc = jnp.zeros(o_ref.shape, jnp.float32)
+    for c in range(k):
+        # exact 0/1 gather of choice c's slot row, then the gate weight in
+        # f32 on the VPU (as the reference applies it)
+        idx_c = lane_column(idx, c)                     # [bt, 1]
+        w_c = jnp.sum(jnp.where(lane == c, w, 0.0), axis=1, keepdims=True)
+        local = idx_c - j * block_rows
+        inside = (idx_c >= 0) & (local >= 0) & (local < block_rows)
+        acc = acc + _gather_dot((local == col) & inside, buf) \
+            * jnp.where(inside, w_c, 0.0)
+    o_ref[...] += acc
 
 
 def combine_rows(buf, rows, weights, *, block_t: int = 1024,
@@ -204,7 +225,7 @@ def combine_rows(buf, rows, weights, *, block_t: int = 1024,
     if r_pad != r:
         buf = jnp.pad(buf, ((0, r_pad - r), (0, 0)))
     out = pl.pallas_call(
-        functools.partial(_combine_kernel, block_rows=brf),
+        functools.partial(_combine_kernel, k=k, block_rows=brf),
         grid=(t_pad // bt, r_pad // brf),
         in_specs=[
             pl.BlockSpec((bt, k), lambda i, j: (i, 0)),
@@ -219,28 +240,45 @@ def combine_rows(buf, rows, weights, *, block_t: int = 1024,
 
 
 def _route_kernel(idx_ref, pos_ref, cum_ref, slot_ref, o_ref, *,
-                  slot_cap: int):
+                  k: int, n_experts: int, slot_cap: int):
     # bin partition: replica r owns positions [cum[r-1], cum[r]) of its
     # expert's GShard priority ranks.  Zero-weight (incl. dead/padded)
     # replicas never advance the cumsum, so they own an empty bin and are
     # skipped; pos >= total (= cum[-1]) is dropped.  Pure int32 arithmetic —
-    # exactly equal to the XLA reference on both backends.
-    idx_raw = idx_ref[...]                              # [bt, k]
-    idx = jnp.maximum(idx_raw, 0)
-    pos = pos_ref[...]                                  # [bt, k]
-    cum = jnp.take(cum_ref[...], idx, axis=0)           # [bt, k, R]
-    rw = cum.shape[-1]
-    total = cum[..., -1]
-    ge = pos[..., None] >= cum                          # [bt, k, R]
-    which = jnp.minimum(jnp.sum(ge.astype(jnp.int32), axis=-1), rw - 1)
-    prev = jnp.max(jnp.where(ge, cum, 0), axis=-1)      # cum[which-1] or 0
-    slotvals = jnp.take(slot_ref[...], idx, axis=0)     # [bt, k, R]
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, cum.shape, 2)
-    slot = jnp.sum(jnp.where(r_iota == which[..., None], slotvals, 0),
-                   axis=-1)
-    rows = slot * slot_cap + (pos - prev)
-    keep = (idx_raw >= 0) & (pos < total) & (slot >= 0)
-    o_ref[...] = jnp.where(keep, rows, -1)
+    # exactly equal to the XLA reference on both backends.  The [E, R]
+    # table rows are selected by an unrolled compare-and-select over the
+    # experts (metadata-sized; no in-kernel gather).
+    idx_all = idx_ref[...]                              # [bt, k]
+    pos_all = pos_ref[...]
+    cum_tab = cum_ref[...]                              # [E, R]
+    slot_tab = slot_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, idx_all.shape, 1)
+    bt = idx_all.shape[0]
+    rw = cum_tab.shape[-1]
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, rw), 1)
+    out = jnp.full(idx_all.shape, -1, jnp.int32)
+    for c in range(k):
+        idx_raw = lane_column(idx_all, c)               # [bt, 1]
+        pos = lane_column(pos_all, c)
+        cum = jnp.zeros((bt, rw), jnp.int32)
+        slotvals = jnp.zeros((bt, rw), jnp.int32)
+        for e in range(n_experts):
+            hit = idx_raw == e
+            cum = jnp.where(hit, cum_tab[e:e + 1, :], cum)
+            slotvals = jnp.where(hit, slot_tab[e:e + 1, :], slotvals)
+        total = jnp.sum(jnp.where(r_iota == rw - 1, cum, 0), axis=1,
+                        keepdims=True)
+        ge = pos >= cum                                 # [bt, R]
+        which = jnp.minimum(jnp.sum(ge.astype(jnp.int32), axis=1,
+                                    keepdims=True), rw - 1)
+        prev = jnp.max(jnp.where(ge, cum, 0), axis=1,
+                       keepdims=True)                   # cum[which-1] or 0
+        slot = jnp.sum(jnp.where(r_iota == which, slotvals, 0), axis=1,
+                       keepdims=True)
+        rows = slot * slot_cap + (pos - prev)
+        keep = (idx_raw >= 0) & (pos < total) & (slot >= 0)
+        out = jnp.where(lane == c, jnp.where(keep, rows, -1), out)
+    o_ref[...] = out
 
 
 def weighted_route(expert_idx, position, cum_weights, slot_of,
@@ -271,7 +309,8 @@ def weighted_route(expert_idx, position, cum_weights, slot_of,
         position = jnp.pad(position, ((0, t_pad - t), (0, 0)))
     e, rw = cum_weights.shape
     out = pl.pallas_call(
-        functools.partial(_route_kernel, slot_cap=int(slot_cap)),
+        functools.partial(_route_kernel, k=k, n_experts=e,
+                          slot_cap=int(slot_cap)),
         grid=(t_pad // bt,),
         in_specs=[
             pl.BlockSpec((bt, k), lambda i: (i, 0)),

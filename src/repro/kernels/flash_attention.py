@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -61,8 +63,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: [B, Sq, H, hd]; k/v: [B, Skv, KV, hd] -> [B, Sq, H, hd]."""
+    if interpret is None:
+        interpret = default_interpret()
     b, sq, h, hd = q.shape
     _, skv, kvh, _ = k.shape
     rep = h // kvh

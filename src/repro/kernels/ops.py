@@ -1,9 +1,9 @@
 """Public jit'd kernel wrappers — the ONLY kernel entry point models use.
 
-On TPU the Pallas kernels compile natively; this container is CPU-only, so
-``interpret=True`` executes the kernel bodies in Python for correctness
-validation (the tests sweep shapes/dtypes against ref.py).  ``use_pallas``
-defaults to the backend: models call these ops and transparently get the
+On a TPU backend the Pallas kernels compile natively (Mosaic).  On the CPU
+test backend ``interpret=True`` executes the kernel bodies on the host for
+correctness validation (the tests sweep shapes/dtypes against ref.py).
+``use_pallas`` defaults to the backend: models call these ops and get the
 kernel on TPU and the jnp oracle on CPU; passing ``use_pallas=True`` on CPU
 forces interpret-mode kernels (the parity-test / ``compute_backend="pallas"``
 path).
@@ -115,7 +115,8 @@ def grouped_ffn_op(x, wi, wu, wo, ffn_type: str = "swiglu",
 # ---------------------------------------------------------------------------
 
 def _gating_oracle(x, router, k):
-    return ref.ref_topk_gating(x @ router, k)
+    from repro.core.gating import router_logits
+    return ref.ref_topk_gating(router_logits(x, router), k)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import default_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, chunk: int):
     ci = pl.program_id(1)
@@ -22,7 +24,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, chunk: int):
     def _():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    u = u_ref[0].astype(jnp.float32)                  # [hd]
+    u = u_ref[0, 0].astype(jnp.float32)               # [hd]
 
     def step(t, s):
         r_t = r_ref[0, t].astype(jnp.float32)         # [hd]
@@ -38,8 +40,11 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, chunk: int):
     s_scr[...] = s
 
 
-def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64,
+              interpret: bool | None = None):
     """r/k/v/w: [B, T, H, hd] (w = log decay); u: [H, hd] -> y [B,T,H,hd]."""
+    if interpret is None:
+        interpret = default_interpret()
     b, t, h, hd = r.shape
     c = min(chunk, t)
     while t % c:
@@ -48,7 +53,8 @@ def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
     def to_bh(a):
         return a.transpose(0, 2, 1, 3).reshape(b * h, t, hd)
     rr, kk, vv, ww = map(to_bh, (r, k, v, w))
-    uu = jnp.broadcast_to(u[None], (b, h, hd)).reshape(b * h, hd)
+    # [B*H, 1, hd]: a (1, 1, hd) block keeps the last two dims at full extent
+    uu = jnp.broadcast_to(u[None], (b, h, hd)).reshape(b * h, 1, hd)
 
     out = pl.pallas_call(
         functools.partial(_kernel, chunk=c),
@@ -58,7 +64,7 @@ def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
             pl.BlockSpec((1, c, hd), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, c, hd), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, c, hd), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, hd), lambda bh, ci: (bh, 0)),
+            pl.BlockSpec((1, 1, hd), lambda bh, ci: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, c, hd), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, hd), jnp.float32),

@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import default_interpret
+
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, h_scr, *,
             q: int):
@@ -24,11 +26,11 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, h_scr, *,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0].astype(jnp.float32)                  # [Q, P]
-    dt = jax.nn.softplus(dt_ref[0].astype(jnp.float32))   # [Q]
-    a = -jnp.exp(a_ref[0, 0].astype(jnp.float32))     # scalar
+    dt = jax.nn.softplus(dt_ref[0, 0].astype(jnp.float32))   # [Q]
+    a = -jnp.exp(a_ref[0, 0, 0].astype(jnp.float32))  # scalar
     bmat = b_ref[0].astype(jnp.float32)               # [Q, N]
     cmat = c_ref[0].astype(jnp.float32)               # [Q, N]
-    d = d_ref[0, 0].astype(jnp.float32)               # scalar
+    d = d_ref[0, 0, 0].astype(jnp.float32)            # scalar
 
     la = dt * a                                       # [Q] log-decay/step
     lcum = jnp.cumsum(la)                             # [Q]
@@ -57,9 +59,11 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, h_scr, *,
 
 
 def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 128,
-             interpret: bool = True):
+             interpret: bool | None = None):
     """x: [B,T,H,P]; dt: [B,T,H]; a_log,d_skip: [H]; b,c: [B,T,N]
     -> y [B,T,H,P] f32."""
+    if interpret is None:
+        interpret = default_interpret()
     bsz, t, h, p = x.shape
     n = b.shape[-1]
     q = min(chunk, t)
@@ -67,22 +71,24 @@ def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 128,
         q //= 2
 
     xh = x.transpose(0, 2, 1, 3).reshape(bsz * h, t, p)
-    dth = dt.transpose(0, 2, 1).reshape(bsz * h, t)
+    # per-head rows get a unit middle dim so every block's last two dims
+    # are tile-aligned or at full extent
+    dth = dt.transpose(0, 2, 1).reshape(bsz * h, 1, t)
     bh = jnp.broadcast_to(b[:, None], (bsz, h, t, n)).reshape(bsz * h, t, n)
     ch = jnp.broadcast_to(c[:, None], (bsz, h, t, n)).reshape(bsz * h, t, n)
-    ah = jnp.broadcast_to(a_log[None], (bsz, h)).reshape(bsz * h, 1)
-    dh = jnp.broadcast_to(d_skip[None], (bsz, h)).reshape(bsz * h, 1)
+    ah = jnp.broadcast_to(a_log[None], (bsz, h)).reshape(bsz * h, 1, 1)
+    dh = jnp.broadcast_to(d_skip[None], (bsz, h)).reshape(bsz * h, 1, 1)
 
     out = pl.pallas_call(
         functools.partial(_kernel, q=q),
         grid=(bsz * h, t // q),
         in_specs=[
             pl.BlockSpec((1, q, p), lambda g, ci: (g, ci, 0)),
-            pl.BlockSpec((1, q), lambda g, ci: (g, ci)),
-            pl.BlockSpec((1, 1), lambda g, ci: (g, 0)),
+            pl.BlockSpec((1, 1, q), lambda g, ci: (g, 0, ci)),
+            pl.BlockSpec((1, 1, 1), lambda g, ci: (g, 0, 0)),
             pl.BlockSpec((1, q, n), lambda g, ci: (g, ci, 0)),
             pl.BlockSpec((1, q, n), lambda g, ci: (g, ci, 0)),
-            pl.BlockSpec((1, 1), lambda g, ci: (g, 0)),
+            pl.BlockSpec((1, 1, 1), lambda g, ci: (g, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, q, p), lambda g, ci: (g, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((bsz * h, t, p), jnp.float32),

@@ -10,6 +10,7 @@ the pad back off.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 LANE = 128     # MXU/VPU lane width — ideal multiple for blocked dims
@@ -39,10 +40,19 @@ def block_bytes(shape, dtype) -> int:
 
 
 def default_interpret() -> bool:
-    """Pallas kernels compile natively on TPU; everywhere else the bodies
-    run in interpret mode (the correctness-validation path in this
-    CPU-only container)."""
+    """Pallas kernels compile natively on a TPU backend; on any other
+    backend (the CPU the tests run on) the bodies run in interpret mode."""
     return jax.default_backend() != "tpu"
+
+
+def lane_column(a, c: int):
+    """Column ``c`` of a narrow [bt, k] int block, as [bt, 1], inside a
+    kernel: a lane select and an f32 lane reduction (exact for
+    |values| < 2**24), which Mosaic lowers for any k, unlike a width-1
+    lane slice."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    sel = jnp.where(lane == c, a.astype(jnp.float32), 0.0)
+    return jnp.sum(sel, axis=1, keepdims=True).astype(a.dtype)
 
 
 def pad_to(n: int, b: int) -> int:

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.tiling import (LANE, SUBLANE, block_and_pad,
-                                  default_interpret, pad_to)
+                                  default_interpret, lane_column, pad_to)
 
 
 def _softmax_topk(logits, idx_ref, w_ref, probs_ref, k: int):
@@ -49,11 +49,11 @@ def _kernel(logits_ref, idx_ref, w_ref, probs_ref, *, k: int):
 
 def _fused_kernel(x_ref, router_ref, idx_ref, w_ref, probs_ref, *, k: int):
     x = x_ref[...]                                     # [bt, D]
+    # f32 logits, unrounded, as core.gating.router_logits gives the XLA
+    # path: both backends rank experts on the same values
     logits = jnp.dot(x, router_ref[...],
                      preferred_element_type=jnp.float32)
-    # round like the unfused XLA path (bf16 matmul emits bf16) so both
-    # backends pick identical experts
-    _softmax_topk(logits.astype(x.dtype), idx_ref, w_ref, probs_ref, k)
+    _softmax_topk(logits, idx_ref, w_ref, probs_ref, k)
 
 
 def topk_gating_fused(logits_or_x, k: int = 2, *, router=None,
@@ -99,29 +99,59 @@ def topk_gating_fused(logits_or_x, k: int = 2, *, router=None,
     return idx[:t], w[:t], probs[:t]
 
 
-def _pos_kernel(idx_ref, pos_ref, cnt_ref, *, e_pad: int):
-    # The per-expert counter lives in the revisited second output block
-    # (CONST index map -> persistent across grid steps); padded token rows
-    # carry expert id -1, so their one-hot is all-zero and they neither
-    # take a rank nor advance the counter.
-    j = pl.program_id(0)
+def _pos_kernel(idx_ref, pos_ref, cnt_ref, *, k: int, e_pad: int):
+    # Two sweeps over the token tiles (grid dim 0 = phase).  Phase 0 counts
+    # each choice's assignments per expert into counter rows [0, k); phase 1
+    # ranks every (token, choice), carrying running counts in rows [k, 2k),
+    # with choice c offset by the totals of choices < c — all first choices
+    # outrank all second choices (GShard priority).  The counter lives in
+    # the revisited second output block (CONST index map -> persistent
+    # across grid steps).  The position block's index map is (i * phase),
+    # so phase 0 never moves it off block 0 and no unwritten block is
+    # stored.  Padded rows carry expert id -1: an all-zero one-hot.
+    ph = pl.program_id(0)
     i = pl.program_id(1)
 
-    @pl.when((j == 0) & (i == 0))
+    @pl.when((ph == 0) & (i == 0))
     def _():
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    idx = idx_ref[...][:, 0]                            # [bt]
-    onehot = (idx[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (idx.shape[0], e_pad), 1)).astype(jnp.int32)
-    base = cnt_ref[0, :]                                # [e_pad]
-    rank = jnp.cumsum(onehot, axis=0) - onehot
-    pos_ref[...] = jnp.sum((rank + base[None, :]) * onehot,
-                           axis=1)[:, None]
-    cnt_ref[0, :] = base + jnp.sum(onehot, axis=0)
+    idx = idx_ref[...]                                  # [bt, k]
+    bt = idx.shape[0]
+    e_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, e_pad), 1)
+    onehots = [(lane_column(idx, c) == e_iota).astype(jnp.float32)
+               for c in range(k)]                       # k x [bt, e_pad]
+
+    @pl.when(ph == 0)
+    def _():
+        for c in range(k):
+            cnt_ref[c:c + 1, :] += jnp.sum(onehots[c], axis=0,
+                                           keepdims=True).astype(jnp.int32)
+
+    @pl.when(ph == 1)
+    def _():
+        # rank within the tile = strictly-lower-triangular 0/1 matmul on the
+        # MXU (bf16 operands, f32 accumulation: exact for counts < 2**24)
+        r_io = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 0)
+        c_io = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 1)
+        tri = (r_io > c_io).astype(jnp.bfloat16)
+        lane = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
+        out = jnp.zeros(idx.shape, jnp.int32)
+        offset = jnp.zeros((1, e_pad), jnp.int32)
+        for c in range(k):
+            oh = onehots[c]
+            rank = jnp.dot(tri, oh.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
+            base = (cnt_ref[k + c:k + c + 1, :] + offset).astype(jnp.float32)
+            pos = jnp.sum((rank + base) * oh, axis=1, keepdims=True)
+            out = jnp.where(lane == c, pos.astype(jnp.int32), out)
+            cnt_ref[k + c:k + c + 1, :] += jnp.sum(
+                oh, axis=0, keepdims=True).astype(jnp.int32)
+            offset = offset + cnt_ref[c:c + 1, :]
+        pos_ref[...] = out
 
 
-def topk_positions(expert_idx, n_experts: int, *, block_t: int = 1024,
+def topk_positions(expert_idx, n_experts: int, *, block_t: int = 512,
                    interpret: bool | None = None):
     """GShard priority positions, fused: expert_idx [T, k] int32 (-1 for
     masked rows) -> position [T, k] int32, the choice-major rank of each
@@ -130,25 +160,28 @@ def topk_positions(expert_idx, n_experts: int, *, block_t: int = 1024,
     ``core.gating.gating_from_topk``, without ever materializing the
     [T, k, E] one-hot in HBM.
 
-    Grid (k, T/bt): the choice axis is OUTERMOST so priority order matches
-    the reference; a [1, E] counter block is revisited across all grid
-    steps and carries each expert's running count.
+    Grid (2, T/bt): a counting sweep, then a ranking sweep, over [bt, k]
+    token tiles (the block's last dim is the full k); a [8, E] counter
+    block is revisited across all grid steps.
     """
     if interpret is None:
         interpret = default_interpret()
     t, k = expert_idx.shape
+    if 2 * k > SUBLANE:
+        raise ValueError(f"topk_positions supports k <= {SUBLANE // 2}, "
+                         f"got {k}")
     bt, t_pad = block_and_pad(t, block_t)
     e_pad = pad_to(max(int(n_experts), 1), LANE)
     if t_pad != t:
         expert_idx = jnp.pad(expert_idx, ((0, t_pad - t), (0, 0)),
                              constant_values=-1)
     pos, _ = pl.pallas_call(
-        functools.partial(_pos_kernel, e_pad=e_pad),
-        grid=(k, t_pad // bt),
-        in_specs=[pl.BlockSpec((bt, 1), lambda j, i: (i, j))],
+        functools.partial(_pos_kernel, k=k, e_pad=e_pad),
+        grid=(2, t_pad // bt),
+        in_specs=[pl.BlockSpec((bt, k), lambda ph, i: (i, 0))],
         out_specs=(
-            pl.BlockSpec((bt, 1), lambda j, i: (i, j)),
-            pl.BlockSpec((SUBLANE, e_pad), lambda j, i: (0, 0)),
+            pl.BlockSpec((bt, k), lambda ph, i: (i * ph, 0)),
+            pl.BlockSpec((SUBLANE, e_pad), lambda ph, i: (0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((t_pad, k), jnp.int32),

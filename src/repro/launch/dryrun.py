@@ -71,13 +71,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, lina: bool = True,
         # decode hillclimb: split `model` into (kv-heads x seq) so the KV
         # cache shards fully AND the per-step cache update stays local
         import jax.sharding as jsh
-        from repro.launch.mesh import axis_types_kwargs
+        from repro.launch.mesh import auto_axes
         kvh = cfg.n_kv_heads
         shp = ((2, 16, kvh, 16 // kvh) if multi_pod
                else (16, kvh, 16 // kvh))
         names = ax.MESH_AXES if multi_pod else ax.MESH_AXES[1:]
         mesh = jsh.Mesh(mesh.devices.reshape(shp), names,
-                        **axis_types_kwargs(len(names)))
+                        axis_types=auto_axes(len(names)))
     n_chips = mesh.size
     specs = input_specs(cfg, shape)
     if shape.kind == "train":
@@ -151,8 +151,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, lina: bool = True,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax<=0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_summary(hlo)
     ana = analytic_cost(cfg, shape)

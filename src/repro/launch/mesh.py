@@ -1,46 +1,38 @@
 """Production meshes.  Functions, not module constants, so importing never
-touches jax device state (the dry-run must set XLA_FLAGS first)."""
+touches jax device state (the dry-run must set XLA_FLAGS first).
+
+Every mesh here uses ``Auto`` axis types: the shard_map bodies and the
+partitioner-driven jit code in this repo rely on implicit (auto) sharding
+propagation, not on JAX's explicit-sharding mode."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.core import axes
 
 
 def mesh_context(mesh):
-    """Activate ``mesh`` as the ambient mesh, across JAX versions.
-
-    Newer JAX spells this ``jax.set_mesh`` (or ``jax.sharding.use_mesh``);
-    the pinned 0.4.x only offers ``Mesh.__enter__``.  All three return a
-    context manager, so callers write ``with mesh_context(mesh):``.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager
+    """Activate ``mesh`` as the ambient mesh (``with mesh_context(mesh):``)."""
+    return jax.set_mesh(mesh)
 
 
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the JAX version has AxisType; {} on
-    the pinned 0.4.x (whose meshes are implicitly fully auto)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {} if at is None else {"axis_types": (at.Auto,) * n_axes}
+def auto_axes(n_axes: int) -> tuple:
+    """``axis_types`` for an all-``Auto`` mesh of ``n_axes`` axes."""
+    return (AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     names = (axes.POD, axes.DATA, axes.MODEL) if multi_pod \
         else (axes.DATA, axes.MODEL)
-    return jax.make_mesh(shape, names, **axis_types_kwargs(len(names)))
+    return jax.make_mesh(shape, names, axis_types=auto_axes(len(names)))
 
 
-def make_mesh(shape, axes):
+def make_mesh(shape, axes, devices=None):
     """Arbitrary (test-sized) mesh with the same axis conventions."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **axis_types_kwargs(len(axes)))
+                         axis_types=auto_axes(len(axes)), devices=devices)
 
 
 def dp_size(mesh) -> int:
@@ -73,4 +65,4 @@ def arch_mesh(cfg, *, multi_pod: bool = False):
     names = axes.MESH_AXES if multi_pod else axes.MESH_AXES[1:]
     import jax.sharding as jsh
     return jsh.Mesh(mesh.devices.reshape(shape), names,
-                    **axis_types_kwargs(len(names)))
+                    axis_types=auto_axes(len(names)))

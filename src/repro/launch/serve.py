@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.device import enable_compile_cache, require_tpu
 from repro.models import lm as lm_mod
 from repro.obs import ObsContext
 from repro.runtime.engine import (EngineConfig, ServingEngine, simulate,
@@ -106,7 +107,12 @@ def main(argv=None):
                     help="write a Prometheus-text metrics snapshot here "
                          "(metrics are collected even without --trace-dir)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--require-tpu", action="store_true",
+                    help="fail unless JAX's devices are TPUs")
     args = ap.parse_args(argv)
+    if args.require_tpu:
+        require_tpu()
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
 
     cfg = get_config(args.arch)
     assert cfg.moe.enabled, "serve driver targets MoE archs"

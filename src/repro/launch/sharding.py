@@ -133,6 +133,37 @@ def opt_state_specs(param_spec_tree, opt_state: OptState) -> OptState:
     return OptState(step=P(), m=param_spec_tree, v=param_spec_tree)
 
 
+def _strip_axes(spec, names):
+    """``spec`` with the mesh axes in ``names`` removed (those dims then
+    replicate over them)."""
+    if spec is None or not isinstance(spec, P):
+        return spec
+    out = []
+    for e in spec:
+        if isinstance(e, tuple):
+            kept = tuple(a for a in e if a not in names)
+            out.append(kept if kept else None)
+        else:
+            out.append(None if e in names else e)
+    return P(*out)
+
+
+def reduce_specs(cfg: ModelConfig, mesh, grads):
+    """Per-leaf PartitionSpec for the explicit data-parallel gradient
+    reduce (``optim.reduce.reduce_gradients``): the training layout without
+    the axes the reduce runs over.  Each device then reduces only its own
+    model/tp shard; replicating every gradient instead would gather the
+    expert gradients across the expert axis."""
+    dp = set(axes.dp_axes(mesh))
+
+    def one(spec, g):
+        if g is None:
+            return None
+        return safe_spec(mesh, _strip_axes(spec or P(), dp), g.shape)
+    return jax.tree.map(one, param_specs(cfg, mesh, grads), grads,
+                        is_leaf=lambda s: isinstance(s, P) or s is None)
+
+
 def serve_uses_fsdp(cfg: ModelConfig, mesh, budget_bytes: float = 10e9) -> bool:
     ep = 1
     for a, s in zip(mesh.axis_names, mesh.devices.shape):
@@ -156,22 +187,7 @@ def serve_param_specs(cfg: ModelConfig, mesh, params: LMParams,
     if per_dev > budget_bytes:
         return specs
     dp_names = set(axes.DP_AXES)
-
-    def strip(spec):
-        if spec is None or not isinstance(spec, P):
-            return spec
-        out = []
-        for e in spec:
-            if e is None:
-                out.append(None)
-            elif isinstance(e, tuple):
-                kept = tuple(a for a in e if a not in dp_names)
-                out.append(kept if kept else None)
-            else:
-                out.append(None if e in dp_names else e)
-        return P(*out)
-
-    return jax.tree.map(strip, specs,
+    return jax.tree.map(lambda s: _strip_axes(s, dp_names), specs,
                         is_leaf=lambda s: isinstance(s, P) or s is None)
 
 

@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.serving import PlanArrays
 from repro.core.placement import identity_plan
+from repro.launch.sharding import reduce_specs
 from repro.models import lm as lm_mod
 from repro.models.lm import LMCache, LMParams, FRAME_DIM
 from repro.optim.adamw import AdamWConfig, OptState, adamw_update, init_opt_state
@@ -141,8 +142,9 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: Optional[AdamWConfig] = Non
         # grad leaves are computed from tokens received over it, and the
         # forward marker pins the forward a2a micro-ops too
         after = reduce_mod.backward_a2a_token(grads, marker)
-        return reduce_mod.reduce_gradients(mesh, grads, rcfg,
-                                           after=after, state=rstate)
+        specs = reduce_specs(cfg, mesh, grads) if mesh is not None else None
+        return reduce_mod.reduce_gradients(mesh, grads, rcfg, after=after,
+                                           state=rstate, specs=specs)
 
     def grads_of(params, batch, rstate):
         """Returns (grads, loss, aux, rstate) with grads already reduced

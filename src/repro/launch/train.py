@@ -3,8 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch gpt2-moe-smoke \
         --steps 50 --batch 8 --seq 128 [--no-lina] [--ckpt-dir /tmp/ckpt]
 
-Smoke-scale on CPU; on a TPU cluster the same entry point runs the
-production mesh (--mesh 16x16) with the dry-run-validated shardings.
+Runs on whatever JAX finds: smoke-scale on CPU for tests, or on a TPU
+(``--require-tpu`` refuses any other backend; ``--mesh`` lays the devices
+out as data x model).
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ import json
 
 from repro.configs import get_config
 from repro.data import DataConfig
+from repro.launch.device import enable_compile_cache, require_tpu
 from repro.optim.adamw import AdamWConfig
 from repro.optim.reduce import DEFAULT_PARTITION_BYTES
 from repro.runtime import Trainer, TrainerConfig
@@ -73,7 +75,9 @@ def main(argv=None):
                     help="data x model mesh, e.g. 2x4 (needs that many "
                          "devices; on CPU force them with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory (resume from its newest "
+                         "checkpoint); checkpoints are off without it")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--metrics-out", default=None,
@@ -83,11 +87,16 @@ def main(argv=None):
                          "(trace.json Chrome trace for Perfetto, spans.json, "
                          "metrics.prom/.json) into this directory")
     ap.add_argument("--jax-profile-dir", default=None,
-                    help="capture a guarded jax.profiler trace window "
+                    help="capture a jax.profiler trace window "
                          "(steps 2..5) into this TensorBoard logdir — the "
                          "device-time fwd/bwd split the host spans cannot "
-                         "see; degrades to a no-op when capture fails")
+                         "see; fails the run if the capture cannot start")
+    ap.add_argument("--require-tpu", action="store_true",
+                    help="fail unless JAX's devices are TPUs")
     args = ap.parse_args(argv)
+    if args.require_tpu:
+        require_tpu()
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
 
     cfg = get_config(args.arch)
     if args.compute_backend is not None:

@@ -66,7 +66,7 @@ def axis_size(mesh, axes) -> int:
         axes = (axes,)
     n = 1
     for a in axes:
-        n *= dict(zip(mesh.axis_names, mesh.devices.shape)).get(a, 1)
+        n *= dict(mesh.shape).get(a, 1)
     return n
 
 

@@ -3,11 +3,12 @@
 Two pieces:
 
   * ``trace_session`` / ``StepProfiler`` — optional ``jax.profiler`` trace
-    capture around N steps, guarded so CPU CI (and builds without
-    tensorboard_plugin_profile) degrade to a no-op instead of failing.
-    The captured TensorBoard trace is where the fwd/bwd device-time split
-    inside a jitted train step actually lives; the host-side spans around
-    it (``runtime.trainer``) carry the schedule attribution.
+    capture around N steps.  Off unless given a log directory; once a trace
+    is requested, a failure to start or stop it raises (a run that asked
+    for a device trace must not finish without one).  The captured trace
+    is where the fwd/bwd device-time split inside a jitted train step
+    actually lives; the host-side spans around it (``runtime.trainer``)
+    carry the schedule attribution.
 
   * ``attribute_overlap`` — replays the overlap microbench's measured
     phases (per-variant serial baseline, a2a-only reference, pipelined
@@ -31,9 +32,8 @@ __all__ = ["trace_session", "StepProfiler", "attribute_overlap",
 
 class trace_session:
     """Context manager around ``jax.profiler.start_trace`` /
-    ``stop_trace``.  ``active`` reports whether a device trace is actually
-    being captured — False on import/start failure (CPU CI keeps running,
-    the host-side span tracer is unaffected)."""
+    ``stop_trace``.  ``active`` reports whether a device trace is being
+    captured; errors from starting or stopping it propagate."""
 
     def __init__(self, logdir: Optional[str], enabled: bool = True):
         self.logdir = logdir
@@ -43,22 +43,16 @@ class trace_session:
     def __enter__(self) -> "trace_session":
         if not self.enabled:
             return self
-        try:
-            import jax
-            jax.profiler.start_trace(self.logdir)
-            self.active = True
-        except Exception:
-            self.active = False
+        import jax
+        jax.profiler.start_trace(self.logdir)
+        self.active = True
         return self
 
     def __exit__(self, *exc):
         if self.active:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
+            import jax
             self.active = False
+            jax.profiler.stop_trace()
         return False
 
 

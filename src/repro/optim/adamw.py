@@ -30,7 +30,9 @@ class OptState(NamedTuple):
 
 def init_opt_state(params, cfg: AdamWConfig) -> OptState:
     dt = jnp.dtype(cfg.state_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, dt)
+    # zeros_like lays each moment out like its parameter (sharded params ->
+    # moments created sharded in place)
+    zeros = lambda p: jnp.zeros_like(p, dtype=dt)
     return OptState(jnp.zeros((), jnp.int32),
                     jax.tree.map(zeros, params),
                     jax.tree.map(zeros, params))

@@ -67,9 +67,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import axes, microop
 from repro.optim.compression import (Int8State, compress_int8_ef,
@@ -139,10 +138,11 @@ def _reduce_shard(grads, int8_state, after, *, axes, cfg: ReduceConfig,
                   n_chunks: int):
     """Reduce (mean) ``grads`` over ``axes`` under schedule ``cfg``.
 
-    Runs per-device.  Returns (reduced_grads, new_int8_state).  The int8
-    path assumes gradients enter replicated over ``axes`` (true for this
-    repo's train step), so each device's quantization scale agrees and the
-    integer psum-mean dequantizes exactly like a local dequantize.
+    Runs per-device on each device's shard.  Returns (reduced_grads,
+    new_int8_state).  The int8 path assumes gradients enter replicated over
+    ``axes`` (true for this repo's train step), so the devices reducing one
+    shard agree on its quantization scale and the integer psum-mean
+    dequantizes exactly like a local dequantize.
     """
     tok = after if cfg.ordered else None
     if cfg.compression == "bf16":
@@ -178,7 +178,8 @@ def _reduce_shard(grads, int8_state, after, *, axes, cfg: ReduceConfig,
 
 def reduce_gradients(mesh, grads, cfg: ReduceConfig, *,
                      after: Optional[jax.Array] = None,
-                     state: Optional[ReduceState] = None):
+                     state: Optional[ReduceState] = None,
+                     specs=None):
     """Explicit DP-axis gradient reduction under Lina's schedule.
 
     mesh:   the training mesh (None -> the 1-device default mesh, where the
@@ -187,6 +188,10 @@ def reduce_gradients(mesh, grads, cfg: ReduceConfig, *,
     after:  backward-a2a completion token (see ``backward_a2a_token``);
             ignored by ``baseline``.
     state:  ``ReduceState`` for int8-EF, else None.
+    specs:  PartitionSpec per gradient leaf over the axes the reduce does
+            NOT run over (``launch.sharding.reduce_specs``): each device
+            then reduces only its own shard.  None replicates every
+            gradient on every device.
 
     Returns (reduced_grads, new_state).
     """
@@ -205,13 +210,13 @@ def reduce_gradients(mesh, grads, cfg: ReduceConfig, *,
                          "ReduceState (see init_reduce_state)")
 
     body = partial(_reduce_shard, axes=axes, cfg=cfg, n_chunks=n_chunks)
-    rep = jax.tree.map(lambda _: P(), grads)
-    st_spec = jax.tree.map(lambda _: P(), int8_state)
+    gspec = specs if specs is not None else jax.tree.map(lambda _: P(), grads)
+    st_spec = None if int8_state is None else Int8State(gspec)
     red, new_int8 = shard_map(
         body, mesh=mesh,
-        in_specs=(rep, st_spec, P()),
-        out_specs=(rep, st_spec),
-        check_rep=False,
+        in_specs=(gspec, st_spec, P()),
+        out_specs=(gspec, st_spec),
+        check_vma=False,
     )(grads, int8_state, after)
     new_state = ReduceState(new_int8) if new_int8 is not None else state
     return red, new_state

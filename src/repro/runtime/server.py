@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.gating import capacity
+from repro.core.gating import capacity, router_logits
 from repro.core.placement import (PlacementPlan, PlanCache, identity_plan,
                                   needs_finetune, plan_from_replicas,
                                   plan_placement, route_weights)
@@ -331,7 +331,7 @@ class MoEServer:
         return x + y, kv.k, kv.v
 
     def _gate_fn(self, router, h2):
-        logits = h2 @ router
+        logits = router_logits(h2, router)
         probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
         _, idx = jax.lax.top_k(probs, self.scfg.top_k)
         return probs, idx.astype(jnp.int32)

@@ -20,6 +20,7 @@ Production behaviors exercised here (and in tests):
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Callable, Optional
 
 import jax
@@ -28,9 +29,12 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.obs import ObsContext
 from repro.configs.base import ModelConfig
+from repro.core.moe import default_mesh
 from repro.core.packing import choose_packing
 from repro.data import DataConfig, SyntheticLM
 from repro.launch.mesh import ep_size
+from repro.launch.sharding import (opt_state_specs, param_specs,
+                                   shardings_for)
 from repro.launch.steps import make_train_step
 from repro.models import lm as lm_mod
 from repro.optim import reduce as reduce_mod
@@ -40,7 +44,7 @@ from repro.optim.adamw import AdamWConfig, init_opt_state
 @dataclass
 class TrainerConfig:
     steps: int = 100
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: Optional[str] = None           # None: checkpoints off
     ckpt_every: int = 50
     keep: int = 3
     log_every: int = 10
@@ -95,15 +99,31 @@ class Trainer:
         self.opt_cfg = opt_cfg
         self.cfg = cfg
         self.mesh = mesh
-        self.ckpt = CheckpointManager(cfg.ckpt_dir, keep=cfg.keep)
+        self.ckpt = (CheckpointManager(cfg.ckpt_dir, keep=cfg.keep)
+                     if cfg.ckpt_dir else None)
         self.dataset = SyntheticLM(data_cfg)
         self.stateful_reduce = cfg.grad_compression == "int8_ef"
+        # params and moments live on the mesh (the 1-device default mesh
+        # when none is given) under the training sharding rules — experts
+        # split over `model`, so each device holds its share — and the step
+        # returns them with the same shardings: step 1 then reuses step 0's
+        # program instead of compiling again for differently placed inputs
+        state_mesh = mesh if mesh is not None else default_mesh()
+        ps = jax.eval_shape(partial(lm_mod.init_params, model_cfg),
+                            jax.random.PRNGKey(0))
+        pspec = param_specs(model_cfg, state_mesh, ps)
+        os_ = jax.eval_shape(partial(init_opt_state, cfg=opt_cfg), ps)
+        self._param_sh = shardings_for(state_mesh, pspec, ps)
+        self._opt_sh = shardings_for(state_mesh, opt_state_specs(pspec, os_),
+                                     os_)
+        n_rest = 2 if self.stateful_reduce else 1    # metrics (+ reduce state)
         self.step_fn = jax.jit(make_train_step(
             model_cfg, mesh, opt_cfg, lina=cfg.lina,
             dispatch_backend=cfg.dispatch_backend,
             microbatches=cfg.microbatches, fsdp=False,
             schedule=cfg.schedule, partition_bytes=cfg.partition_bytes,
-            grad_compression=cfg.grad_compression))
+            grad_compression=cfg.grad_compression),
+            out_shardings=(self._param_sh, self._opt_sh) + (None,) * n_rest)
         self.metrics_log: list = []
         self.straggler_events: list = []
         self.packing_decision = None
@@ -111,10 +131,13 @@ class Trainer:
         self.rollbacks = 0                   # checkpoint rollbacks performed
 
     def init_state(self):
-        params = lm_mod.init_params(self.model_cfg,
-                                    jax.random.PRNGKey(self.cfg.seed))
+        params = jax.device_put(
+            lm_mod.init_params(self.model_cfg,
+                               jax.random.PRNGKey(self.cfg.seed)),
+            self._param_sh)
         state = {"params": params,
-                 "opt_state": init_opt_state(params, self.opt_cfg)}
+                 "opt_state": jax.device_put(
+                     init_opt_state(params, self.opt_cfg), self._opt_sh)}
         if self.stateful_reduce:
             # int8-EF residual rides in the checkpoint so resume is bitwise
             state["reduce_state"] = reduce_mod.init_reduce_state(
@@ -126,12 +149,11 @@ class Trainer:
 
     def run(self, on_step: Optional[Callable] = None) -> dict:
         state = self.init_state()
-        start, restored = self.ckpt.restore_latest(state)
-        if restored is not None:
-            state = restored
-            start_step = start
-        else:
-            start_step = 0
+        start_step = 0
+        if self.ckpt is not None:
+            start, restored = self.ckpt.restore_latest(state)
+            if restored is not None:
+                state, start_step = restored, start
 
         times: list = []
         consec_bad = 0
@@ -173,7 +195,9 @@ class Trainer:
                     ssp.set(skipped=True)
                     consec_bad += 1
                     if consec_bad >= self.cfg.max_bad_steps:
-                        _, rb_state = self.ckpt.restore_latest(state)
+                        rb_state = None
+                        if self.ckpt is not None:
+                            _, rb_state = self.ckpt.restore_latest(state)
                         if rb_state is not None:
                             state = rb_state
                             self.rollbacks += 1
@@ -204,8 +228,9 @@ class Trainer:
                     self._decide_packing()
                 if on_step:
                     on_step(step, m)
-                if (step + 1) % self.cfg.ckpt_every == 0 or \
-                        step + 1 == self.cfg.steps:
+                if self.ckpt is not None and (
+                        (step + 1) % self.cfg.ckpt_every == 0
+                        or step + 1 == self.cfg.steps):
                     with tr.span("checkpoint", step=step + 1):
                         self.ckpt.save(step + 1, state)
         return state

@@ -66,6 +66,21 @@ _SYN_KERNEL = textwrap.dedent('''
             out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
             out_shape=None)(x)
 
+    def bad_unit_lane(x):
+        return pl.pallas_call(
+            _k, grid=(2, 4),
+            in_specs=[pl.BlockSpec((512, 1), lambda j, i: (i, j))],
+            out_specs=pl.BlockSpec((512, 1), lambda j, i: (i, j)),
+            out_shape=None)(x)
+
+    def good_unit_dims(x, y):
+        return pl.pallas_call(
+            _k, grid=(4,),
+            in_specs=[pl.BlockSpec((512, 1), lambda i: (i, 0)),
+                      pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0))],
+            out_specs=pl.BlockSpec((512, 1), lambda i: (i, 0)),
+            out_shape=None)(x, y)
+
     def not_in_registry(x):
         return pl.pallas_call(_k, grid=(1,))(x)
 ''')
@@ -94,6 +109,23 @@ _SYN_REGISTRY = {
         [Block("a", (8, 128), "float32", (grid_dim(0), CONST), (32, 128))],
         [Block("o", (8, 128), "float32", (grid_dim(0), CONST), (32, 128))],
         (3,)),
+    # a (bt, 1) block on a [T, 2] array: the unit lane dim is neither the
+    # full extent nor 128-aligned, which Mosaic refuses
+    ("syn.py", "bad_unit_lane"): _syn_eval(
+        "bad_unit_lane",
+        [Block("idx", (512, 1), "int32", (grid_dim(1), grid_dim(0)),
+               (2048, 2))],
+        [Block("pos", (512, 1), "int32", (grid_dim(1), grid_dim(0)),
+               (2048, 2))],
+        (2, 4)),
+    # unit dims at full extent, or outside the last two dims, are fine
+    ("syn.py", "good_unit_dims"): _syn_eval(
+        "good_unit_dims",
+        [Block("col", (512, 1), "int32", (grid_dim(0), CONST), (2048, 1)),
+         Block("t", (1, 8, 128), "float32", (grid_dim(0), CONST, CONST),
+               (4, 8, 128))],
+        [Block("o", (512, 1), "int32", (grid_dim(0), CONST), (2048, 1))],
+        (4,)),
     ("syn.py", "good_kernel"): _syn_eval(
         "good_kernel",
         [Block("a", (8, 128), "float32", (grid_dim(0), CONST), (32, 128))],
@@ -137,6 +169,16 @@ def test_good_kernel_no_findings(syn_kernels):
     assert _cats(syn_kernels, "good_kernel") == []
 
 
+def test_unit_lane_block_on_wider_array_detected(syn_kernels):
+    fs = [f for f in syn_kernels if f.qualname == "bad_unit_lane"]
+    assert {f.category for f in fs} == {"misaligned-block"}
+    assert {f.key.split("[")[-1] for f in fs} == {"dim1]"}
+
+
+def test_unit_dims_at_full_extent_or_leading_pass(syn_kernels):
+    assert _cats(syn_kernels, "good_unit_dims") == []
+
+
 def test_unregistered_site_detected(syn_kernels):
     assert _cats(syn_kernels, "not_in_registry") == ["unregistered-kernel"]
 
@@ -155,7 +197,7 @@ def test_ast_site_enumeration(tmp_path):
     sites = iter_pallas_sites(str(tmp_path), rel_prefix="syn")
     assert {s.qualname for s in sites} == {
         "bad_misaligned", "bad_overbudget", "bad_uncovered", "good_kernel",
-        "not_in_registry"}
+        "bad_unit_lane", "good_unit_dims", "not_in_registry"}
     by_name = {s.qualname: s for s in sites}
     assert by_name["good_kernel"].grid_len == 1
     assert by_name["good_kernel"].n_in_specs == 1

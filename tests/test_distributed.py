@@ -31,8 +31,8 @@ def run_snippet(body: str, timeout=420):
 def test_moe_layer_lina_equals_baseline_on_mesh():
     out = run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        from repro.launch.mesh import mesh_context
+        from repro.launch.mesh import make_mesh, mesh_context
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.core import init_moe_params, moe_layer
         from repro.configs.base import MoEConfig
         cfg = MoEConfig(n_experts=8, top_k=2, d_ff=32, n_microops=2)
@@ -51,8 +51,8 @@ def test_moe_layer_lina_equals_baseline_on_mesh():
 def test_serve_layer_honors_plan_and_matches_training():
     out = run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        from repro.launch.mesh import mesh_context
+        from repro.launch.mesh import make_mesh, mesh_context
+        mesh = make_mesh((2, 4), ("data", "model"))
         from repro.core import init_moe_params, moe_layer, plan_placement, PlanArrays
         from repro.core.serving import serve_moe_layer
         from repro.configs.base import MoEConfig
@@ -80,9 +80,9 @@ def test_prioritized_chunked_reduce_equals_psum():
     out = run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((8,), ("data",))
-        from repro.launch.mesh import mesh_context
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh, mesh_context
+        mesh = make_mesh((8,), ("data",))
         from repro.core.microop import prioritized_chunked_reduce
         grads = {"a": jnp.arange(40, dtype=jnp.float32).reshape(8, 5),
                  "b": jnp.ones((8, 3)) * 2.0}
@@ -97,7 +97,7 @@ def test_prioritized_chunked_reduce_equals_psum():
             red, plain = jax.jit(shard_map(body, mesh=mesh,
                 in_specs=({"a": P("data", None), "b": P("data", None)},),
                 out_specs=({"a": P("data", None), "b": P("data", None)},)*2,
-                check_rep=False))(grads)
+                check_vma=False))(grads)
         for k in grads:
             assert np.allclose(red[k], plain[k], atol=1e-6), k
         print("OK")
@@ -116,11 +116,11 @@ def test_train_step_schedules_match_baseline_on_dp_mesh():
         from repro.data import DataConfig, SyntheticLM
         from repro.optim.adamw import AdamWConfig, init_opt_state
         from repro.optim import reduce as R
-        from repro.launch.mesh import mesh_context
+        from repro.launch.mesh import make_mesh, mesh_context
         from repro.launch.steps import make_train_step
         from repro.models import lm as lm_mod
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("gpt2-moe").smoke()
         dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=8)
         batch = {k: jnp.asarray(v) for k, v in SyntheticLM(dc).batch(0).items()}
@@ -177,11 +177,11 @@ def test_reduce_shard_really_reduces_distinct_grads():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.launch.mesh import mesh_context
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh, mesh_context
         from repro.optim.reduce import (ReduceConfig, _reduce_shard,
                                         n_chunks_for_bytes)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g = {"w": jnp.arange(96, dtype=jnp.float32).reshape(8, 12) / 7.0,
              "b": jnp.linspace(-1, 1, 24).reshape(8, 3)}
         for sched in ("baseline", "priority", "priority+partition",
@@ -205,7 +205,7 @@ def test_reduce_shard_really_reduces_distinct_grads():
                         body, mesh=mesh,
                         in_specs=({"w": P(), "b": P()},),
                         out_specs={"w": P(), "b": P()},
-                        check_rep=False))(g)
+                        check_vma=False))(g)
                 # mean over devices of (g + idx) = g + 3.5; a skipped psum
                 # would return g + axis_index (g on device 0) instead
                 tol = 0.2 if comp == "bf16" else 1e-5
@@ -222,14 +222,15 @@ def test_elastic_checkpoint_reshard():
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_pytree, load_pytree
+        from repro.launch.mesh import make_mesh
         tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
-        m1 = jax.make_mesh((8,), ("data",))
+        m1 = make_mesh((8,), ("data",))
         t1 = jax.tree.map(lambda a: jax.device_put(
             a, NamedSharding(m1, P("data", None))), tree)
         d = os.path.join(tempfile.mkdtemp(), "ck")
         save_pytree(t1, d)
         # restore onto a DIFFERENT mesh shape (elastic rescale 1x8 -> 2x4)
-        m2 = jax.make_mesh((2, 4), ("data", "model"))
+        m2 = make_mesh((2, 4), ("data", "model"))
         sh = {"w": NamedSharding(m2, P("data", "model"))}
         t2 = load_pytree(d, tree, shardings=sh)
         np.testing.assert_array_equal(np.asarray(t2["w"]), np.asarray(tree["w"]))
@@ -243,9 +244,9 @@ def test_chunked_a2a_equivalence():
     out = run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((8,), ("model",))
-        from repro.launch.mesh import mesh_context
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh, mesh_context
+        mesh = make_mesh((8,), ("model",))
         from repro.core.microop import (all_to_all_ec, all_to_all_ec_inverse,
                                         chunked_all_to_all)
         buf = jax.random.normal(jax.random.PRNGKey(0), (8*8, 16, 4))
@@ -260,7 +261,7 @@ def test_chunked_a2a_equivalence():
             whole, parts, back = jax.jit(shard_map(body, mesh=mesh,
                 in_specs=(P("model", None, None),),
                 out_specs=(P("model", None, None),)*3,
-                check_rep=False))(buf)
+                check_vma=False))(buf)
         assert np.allclose(whole, parts, atol=1e-6)
         assert np.allclose(back, buf, atol=1e-6)   # a2a is its own inverse
         print("OK")

@@ -212,10 +212,10 @@ def test_train_step_pallas_backend_matches_xla_on_mesh():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
         from repro.data import DataConfig, SyntheticLM
-        from repro.launch.mesh import mesh_context
+        from repro.launch.mesh import make_mesh, mesh_context
         from repro.models import lm as lm_mod
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg_x = get_config("gpt2-moe").smoke()
         cfg_p = dataclasses.replace(
             cfg_x, moe=dataclasses.replace(cfg_x.moe,

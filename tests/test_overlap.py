@@ -32,10 +32,10 @@ def test_chunked_a2a_surfaces_chosen_count():
     out = run_snippet("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.launch.mesh import mesh_context
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh, mesh_context
         from repro.core.microop import chunked_all_to_all, resolve_chunk_count
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         buf = jax.random.normal(jax.random.PRNGKey(0), (8, 12, 4))
 
         for req in (1, 4, 5, 100):
@@ -48,7 +48,7 @@ def test_chunked_a2a_surfaces_chosen_count():
                 jax.jit(shard_map(body, mesh=mesh,
                                   in_specs=(P(None, None, None),),
                                   out_specs=P(None, None, None),
-                                  check_rep=False))(buf)
+                                  check_vma=False))(buf)
         print("OK")
     """)
     assert "OK" in out
@@ -63,10 +63,10 @@ def test_pipelined_ffn_equals_serial_across_chunk_counts():
     out = run_snippet("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
-        from repro.launch.mesh import mesh_context
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh, mesh_context
         from repro.core.microop import pipelined_expert_ffn
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         E, C, D = 8, 12, 4
         buf = jax.random.normal(jax.random.PRNGKey(0), (E, C, D))
         w = jax.random.normal(jax.random.PRNGKey(1), (D, D)) * 0.3
@@ -80,7 +80,7 @@ def test_pipelined_ffn_equals_serial_across_chunk_counts():
             with mesh_context(mesh):
                 return np.asarray(jax.jit(shard_map(
                     body, mesh=mesh, in_specs=(P(None, None, None),),
-                    out_specs=P(None, None, None), check_rep=False))(buf))
+                    out_specs=P(None, None, None), check_vma=False))(buf))
 
         ref = run(4, pipeline=False)            # serial baseline
         assert np.array_equal(run(1), ref)      # single-chunk fallback
@@ -100,10 +100,10 @@ _VARIANT_EQUIV = """
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_config
     from repro.data import DataConfig, SyntheticLM
-    from repro.launch.mesh import mesh_context
+    from repro.launch.mesh import make_mesh, mesh_context
     from repro.models import lm as lm_mod
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     base = get_config("gpt2-moe").smoke()
     base = dataclasses.replace(
         base, moe=dataclasses.replace(base.moe,
