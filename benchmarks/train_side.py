@@ -162,6 +162,7 @@ def _measure_schedules_inprocess(schedules, steps, batch, seq, microbatches,
     measured, smoke-scale Fig. 15) and runs the main ablation at the
     measured minimum.  Returns (rows, sweep_rows, partition_bytes)."""
     from repro.launch.mesh import make_mesh, mesh_context
+    from repro.launch.sharding import reduce_specs
     from repro.optim import reduce as reduce_mod
 
     n = jax.device_count()
@@ -210,8 +211,10 @@ def _measure_schedules_inprocess(schedules, steps, batch, seq, microbatches,
         partition_bytes = min(sweep_rows, key=lambda r: r[1])[0]
 
     # grads are params-shaped: report the micro-op count each schedule
-    # actually compiled (non-partitioned schedules run one fused reduce)
-    part_chunks = reduce_mod.n_chunks_for_bytes(params, partition_bytes)
+    # actually compiled (non-partitioned schedules run one fused reduce);
+    # the micro-ops partition each device's shard of the gradients
+    part_chunks = reduce_mod.shard_n_chunks(
+        mesh, params, reduce_specs(cfg, mesh, params), partition_bytes)
     out = []
     for sched in schedules:
         n_chunks = part_chunks if "partition" in sched else 1

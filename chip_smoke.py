@@ -35,6 +35,7 @@ not benchmark measurements.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import gc
 import json
@@ -158,11 +159,37 @@ def parity_phase(cfg, n_tokens: int = 2048):
 # phase 3: serve through the engine
 # ---------------------------------------------------------------------------
 
+# JAX's monitoring events for lowering a jaxpr and for building one
+# executable (a backend compile, or a fetch from the compile cache)
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@contextlib.contextmanager
+def compile_work():
+    """Seconds spent lowering and compiling inside the window, and the
+    number of executables built: {"lower_s", "compile_s", "programs"}."""
+    from jax import monitoring
+    tot = {"lower_s": 0.0, "compile_s": 0.0, "programs": 0}
+
+    def on(event, secs, **_):
+        if event == LOWER_EVENT:
+            tot["lower_s"] += secs
+        elif event == COMPILE_EVENT:
+            tot["compile_s"] += secs
+            tot["programs"] += 1
+    monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield tot
+    finally:
+        monitoring.unregister_event_duration_listener(on)
+
 def serve_phase(cfg, n_requests: int = 8, prompt: int = 128,
                 new_tokens: int = 16, seed: int = SEED):
     import jax
     import numpy as np
 
+    from repro.analysis.retrace import no_retrace
     from repro.data import DataConfig, SyntheticLM
     from repro.models import lm as lm_mod
     from repro.runtime.engine import (EngineConfig, ServingEngine, simulate,
@@ -193,9 +220,20 @@ def serve_phase(cfg, n_requests: int = 8, prompt: int = 128,
     for _ in range(n_requests):
         t += rng.exponential(1.0 / 20.0)
         trace.append((rng.randint(0, cfg.vocab_size, (prompt,)), t))
-    results = simulate(engine, trace, max_new_tokens=new_tokens)
-
     met = engine.obs.metrics
+    layers0 = met.value("server_layers_served_total")
+    tunes0 = met.value("server_phase2_finetunes_total")
+    t0 = time.perf_counter()
+    with no_retrace("simulate", strict=False) as traced, \
+            compile_work() as work:
+        results = simulate(engine, trace, max_new_tokens=new_tokens)
+    sim_s = time.perf_counter() - t0
+    layers = met.value("server_layers_served_total") - layers0
+    tunes = met.value("server_phase2_finetunes_total") - tunes0
+    log(f"[serve] simulate {sim_s:.2f} s: {traced.count} jit traces, "
+        f"{work['programs']} executables built in {work['compile_s']:.2f} s "
+        f"(+{work['lower_s']:.2f} s lowering); phase-2 fine-tunes "
+        f"{tunes:.0f} of {layers:.0f} layer batches")
     offered = met.value("engine_requests_offered_total")
     completed = met.value("engine_requests_completed_total")
     check(len(results) == n_requests,

@@ -13,6 +13,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import router_logits
+
 
 class GatingResult(NamedTuple):
     expert_idx: jax.Array      # [T, k] int32 — chosen expert per token/slot
@@ -88,14 +90,6 @@ def top_k_gating(logits: jax.Array, top_k: int, cap: int,
     gate_w, expert_idx = jax.lax.top_k(probs, top_k)            # [T, k]
     gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
     return gating_from_topk(expert_idx, gate_w, probs, cap, aux_loss_weight)
-
-
-def router_logits(x: jax.Array, router: jax.Array) -> jax.Array:
-    """``x @ router`` accumulated and returned in f32.  Every gating path
-    (XLA, the fused kernel and its oracle, the server's popularity gate)
-    ranks experts on these unrounded logits: rounded to bf16, two experts
-    can tie, and the backends break such a tie differently."""
-    return jnp.dot(x, router, preferred_element_type=jnp.float32)
 
 
 def router_top_k_gating(x: jax.Array, router: jax.Array, top_k: int,

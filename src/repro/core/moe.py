@@ -13,7 +13,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.base import MoEConfig
 from repro.core import axes
@@ -31,8 +31,8 @@ def default_mesh():
     collectives) also runs on a bare CPU — used by smoke tests."""
     global _DEFAULT_MESH
     if _DEFAULT_MESH is None:
-        from repro.launch.mesh import make_mesh
-        _DEFAULT_MESH = make_mesh((1, 1), (axes.DATA, axes.MODEL))
+        _DEFAULT_MESH = jax.make_mesh((1, 1), (axes.DATA, axes.MODEL),
+                                      axis_types=(AxisType.Auto,) * 2)
     return _DEFAULT_MESH
 
 

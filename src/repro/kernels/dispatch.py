@@ -11,13 +11,21 @@ one O(E*C) scatter of ids, no feature data), and:
   * ``dispatch_rows``  — grid over (output-row tile, source tile); each
     output tile is revisited across the streamed source tiles, gathering the
     rows that live in the current tile as a 0/1 one-hot matmul on the MXU
-    (Mosaic has no in-kernel row gather) and accumulating (rows outside the
-    tile contribute exactly 0.0, so the result is bitwise the single-pass
-    gather).  An optional per-row scale also serves the combine-backward,
-    where the scattered rows are gate-weighted cotangents.
+    (Mosaic has no in-kernel row gather) and accumulating (for finite
+    inputs the rows outside the tile contribute exactly 0.0, so the result
+    is bitwise the single-pass gather).  An optional per-row scale also
+    serves the combine-backward, where the scattered rows are gate-weighted
+    cotangents.
   * ``combine_rows``   — grid over (token tile, buffer tile); each token
     tile is revisited across the streamed slot-buffer tiles and reduces its
     k gate-weighted slot rows in fp32.
+
+The one-hot gathers cost O(output rows x source rows x d) MXU work, and
+they keep rows apart only for finite inputs: 0 * NaN and 0 * Inf are NaN,
+so one non-finite source value at (row, feature c) turns feature c of
+EVERY output row into NaN — in serving, every co-batched request's
+output, where a true gather (and the XLA path) keeps the other rows
+intact.  ``tests/test_kernels.py`` records this behaviour.
   * ``weighted_route`` — grid over token tiles; the per-(expert, replica)
     integer routing weights (cumsum form) and the replica->slot table stay
     VMEM-resident while each tile turns (expert, position) into a flat
@@ -100,7 +108,9 @@ def invert_slots(rows, n_rows: int):
 def _gather_dot(onehot, tile):
     """Row gather as a 0/1 matmul on the MXU: ``onehot`` [n, b] selects rows
     of ``tile`` [b, d].  f32 accumulation, and full f32 passes for an f32
-    tile, so each selected row comes out exactly."""
+    tile, so each selected row comes out exactly — when ``tile`` is finite.
+    A NaN or Inf anywhere in feature c of ``tile`` makes feature c of every
+    output row NaN (0 * NaN, 0 * Inf)."""
     prec = (jax.lax.Precision.HIGHEST if tile.dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
     return jnp.dot(onehot.astype(tile.dtype), tile, precision=prec,
@@ -110,8 +120,9 @@ def _gather_dot(onehot, tile):
 def _dispatch_kernel(src_ref, scale_ref, x_ref, o_ref, *, block_src: int):
     # source tiles stream along grid dim 1; the output tile is revisited,
     # zero-initialized on the first source tile and accumulated in fp32.
-    # Each output row's source token lives in exactly one tile; the other
-    # tiles add exactly 0.0, so the sum is bitwise the one-pass gather.
+    # Each output row's source token lives in exactly one tile; for finite
+    # x the other tiles add exactly 0.0, so the sum is bitwise the one-pass
+    # gather (a non-finite x value poisons its feature in every row).
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -136,6 +147,9 @@ def dispatch_rows(x, src_tok, scale=None, *, block_rows: int = 1024,
     dim 1) — nothing scales with the full T extent, so all four paper
     shapes fit the per-core budget at scale=1.  Checked up front via
     ``dispatch_vmem_bytes`` (raises ValueError instead of a silent OOM).
+
+    Equal to ``ref.ref_dispatch_rows`` for finite ``x``; a non-finite value
+    in feature c of ``x`` makes feature c of every output row NaN.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -173,8 +187,9 @@ def dispatch_rows(x, src_tok, scale=None, *, block_rows: int = 1024,
 def _combine_kernel(idx_ref, w_ref, buf_ref, o_ref, *, k: int,
                     block_rows: int):
     # slot-buffer tiles stream along grid dim 1; each (token, choice) hits
-    # exactly one tile (others add 0.0) and fp32 addition is commutative,
-    # so the accumulated weighted sum equals the one-pass reduction bitwise.
+    # exactly one tile (for a finite buffer the others add 0.0) and fp32
+    # addition is commutative, so the accumulated weighted sum equals the
+    # one-pass reduction bitwise.
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -207,6 +222,9 @@ def combine_rows(buf, rows, weights, *, block_t: int = 1024,
 
     VMEM contract: the slot buffer streams in [block_rows, d] tiles (grid
     dim 1) — no R-resident block — checked via ``combine_vmem_bytes``.
+
+    Equal to ``ref.ref_combine_rows`` for a finite ``buf``; a non-finite
+    value in feature c of ``buf`` makes feature c of every output row NaN.
     """
     if interpret is None:
         interpret = default_interpret()

@@ -115,8 +115,7 @@ def grouped_ffn_op(x, wi, wu, wo, ffn_type: str = "swiglu",
 # ---------------------------------------------------------------------------
 
 def _gating_oracle(x, router, k):
-    from repro.core.gating import router_logits
-    return ref.ref_topk_gating(router_logits(x, router), k)
+    return ref.ref_topk_gating(ref.router_logits(x, router), k)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))

@@ -20,6 +20,14 @@ def ref_grouped_ffn(x, wi, wu, wo, ffn_type: str = "swiglu"):
     return jnp.einsum("etf,efd->etd", h, wo).astype(x.dtype)
 
 
+def router_logits(x, router):
+    """``x @ router`` accumulated and returned in f32.  Every gating path
+    (XLA, the fused kernel and its oracle, the server's popularity gate)
+    ranks experts on these unrounded logits: rounded to bf16, two experts
+    can tie, and the backends break such a tie differently."""
+    return jnp.dot(x, router, preferred_element_type=jnp.float32)
+
+
 def ref_topk_gating(logits, k: int):
     """Fused router softmax + top-k.  logits: [T, E].
     Returns (expert_idx [T,k] i32, gate_w [T,k] f32 renormalized, probs)."""

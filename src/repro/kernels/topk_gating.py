@@ -49,7 +49,7 @@ def _kernel(logits_ref, idx_ref, w_ref, probs_ref, *, k: int):
 
 def _fused_kernel(x_ref, router_ref, idx_ref, w_ref, probs_ref, *, k: int):
     x = x_ref[...]                                     # [bt, D]
-    # f32 logits, unrounded, as core.gating.router_logits gives the XLA
+    # f32 logits, unrounded, as ref.router_logits gives the XLA
     # path: both backends rank experts on the same values
     logits = jnp.dot(x, router_ref[...],
                      preferred_element_type=jnp.float32)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.core.moe import default_mesh
 from repro.core.serving import PlanArrays
 from repro.core.placement import identity_plan
 from repro.launch.sharding import reduce_specs
@@ -128,6 +129,7 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: Optional[AdamWConfig] = Non
                                        partition_bytes=partition_bytes,
                                        compression=grad_compression)
     stateful = grad_compression == "int8_ef"
+    reduce_mesh = mesh if mesh is not None else default_mesh()
     pipelined = (rcfg is not None and microbatches > 1 and
                  schedule == "priority+partition+pipeline")
 
@@ -142,9 +144,9 @@ def make_train_step(cfg: ModelConfig, mesh, opt_cfg: Optional[AdamWConfig] = Non
         # grad leaves are computed from tokens received over it, and the
         # forward marker pins the forward a2a micro-ops too
         after = reduce_mod.backward_a2a_token(grads, marker)
-        specs = reduce_specs(cfg, mesh, grads) if mesh is not None else None
-        return reduce_mod.reduce_gradients(mesh, grads, rcfg, after=after,
-                                           state=rstate, specs=specs)
+        specs = reduce_specs(cfg, reduce_mesh, grads)
+        return reduce_mod.reduce_gradients(reduce_mesh, grads, rcfg, specs,
+                                           after=after, state=rstate)
 
     def grads_of(params, batch, rstate):
         """Returns (grads, loss, aux, rstate) with grads already reduced

@@ -8,4 +8,5 @@ from repro.optim.compression import (compress_bf16, decompress_bf16,
                                      decompress_int8)
 from repro.optim.reduce import (SCHEDULES, ReduceConfig, ReduceState,
                                 backward_a2a_token, init_reduce_state,
-                                n_chunks_for_bytes, reduce_gradients)
+                                n_chunks_for_bytes, reduce_gradients,
+                                shard_n_chunks)

@@ -29,7 +29,8 @@ analytically, so measured and simulated rows line up):
                                     differ only through preemption of an
                                     in-flight allreduce (which a static
                                     SPMD program cannot express).
-  ``priority+partition``            uniform micro-op chunks sized by
+  ``priority+partition``            uniform micro-op chunks of each
+                                    device's gradient shard, sized by
                                     ``partition_bytes``, each ordered after
                                     the token and chained among themselves
                                     (Fig. 8a).
@@ -68,7 +69,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import axes, microop
 from repro.optim.compression import (Int8State, compress_int8_ef,
@@ -125,6 +126,19 @@ def n_chunks_for_bytes(grads, partition_bytes: float) -> int:
     return max(1, math.ceil(total / max(float(partition_bytes), 1.0)))
 
 
+def shard_n_chunks(mesh, grads, specs, partition_bytes: float) -> int:
+    """Micro-op count for the shard of ``grads`` that one device reduces
+    under ``specs`` (``reduce_specs``).  The chunked psum partitions each
+    device's own shard, so ``partition_bytes`` is a per-device micro-op
+    size: an expert gradient split over ``ep`` devices counts 1/ep of its
+    bytes, and the count does not change with the expert-parallel degree."""
+    local = jax.tree.map(
+        lambda g, s: jax.ShapeDtypeStruct(
+            NamedSharding(mesh, s).shard_shape(g.shape), g.dtype),
+        grads, specs)
+    return n_chunks_for_bytes(local, partition_bytes)
+
+
 def reduce_axes(mesh) -> tuple:
     """The DP mesh axes the gradient reduction runs over."""
     return axes.dp_axes(mesh)
@@ -176,30 +190,26 @@ def _reduce_shard(grads, int8_state, after, *, axes, cfg: ReduceConfig,
 # top-level entry: global grads -> shard_map -> reduced global grads
 # ---------------------------------------------------------------------------
 
-def reduce_gradients(mesh, grads, cfg: ReduceConfig, *,
+def reduce_gradients(mesh, grads, cfg: ReduceConfig, specs, *,
                      after: Optional[jax.Array] = None,
-                     state: Optional[ReduceState] = None,
-                     specs=None):
+                     state: Optional[ReduceState] = None):
     """Explicit DP-axis gradient reduction under Lina's schedule.
 
-    mesh:   the training mesh (None -> the 1-device default mesh, where the
-            collectives are trivial but the schedule still traces/compiles).
+    mesh:   the training mesh (on the 1-device ``core.moe.default_mesh``
+            the collectives are trivial but the schedule still compiles).
     grads:  the global gradient pytree out of jit-level autodiff.
+    specs:  PartitionSpec per gradient leaf over the axes the reduce does
+            NOT run over (``launch.sharding.reduce_specs``): each device
+            reduces only its own shard, and ``cfg.partition_bytes`` sizes
+            the micro-ops of that shard (``shard_n_chunks``).
     after:  backward-a2a completion token (see ``backward_a2a_token``);
             ignored by ``baseline``.
     state:  ``ReduceState`` for int8-EF, else None.
-    specs:  PartitionSpec per gradient leaf over the axes the reduce does
-            NOT run over (``launch.sharding.reduce_specs``): each device
-            then reduces only its own shard.  None replicates every
-            gradient on every device.
 
     Returns (reduced_grads, new_state).
     """
-    if mesh is None:
-        from repro.core.moe import default_mesh
-        mesh = default_mesh()
     axes = tuple(a for a in reduce_axes(mesh) if a in mesh.axis_names)
-    n_chunks = (n_chunks_for_bytes(grads, cfg.partition_bytes)
+    n_chunks = (shard_n_chunks(mesh, grads, specs, cfg.partition_bytes)
                 if cfg.partitioned else 1)
     if after is None:
         after = jnp.zeros((), jnp.float32)
@@ -210,12 +220,11 @@ def reduce_gradients(mesh, grads, cfg: ReduceConfig, *,
                          "ReduceState (see init_reduce_state)")
 
     body = partial(_reduce_shard, axes=axes, cfg=cfg, n_chunks=n_chunks)
-    gspec = specs if specs is not None else jax.tree.map(lambda _: P(), grads)
-    st_spec = None if int8_state is None else Int8State(gspec)
+    st_spec = None if int8_state is None else Int8State(specs)
     red, new_int8 = shard_map(
         body, mesh=mesh,
-        in_specs=(gspec, st_spec, P()),
-        out_specs=(gspec, st_spec),
+        in_specs=(specs, st_spec, P()),
+        out_specs=(specs, st_spec),
         check_vma=False,
     )(grads, int8_state, after)
     new_state = ReduceState(new_int8) if new_int8 is not None else state

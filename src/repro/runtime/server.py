@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.core.gating import capacity, router_logits
+from repro.core.gating import capacity
 from repro.core.placement import (PlacementPlan, PlanCache, identity_plan,
                                   needs_finetune, plan_from_replicas,
                                   plan_placement, route_weights)
@@ -55,6 +55,7 @@ from repro.core.serving import (PlanArrays, dp_shard_count,
                                 mask_dead_route_weights,
                                 replica_token_counts, serve_moe_layer,
                                 slot_capacity)
+from repro.kernels.ref import router_logits
 from repro.models import lm as lm_mod
 from repro.models.attention import KVCache, attention, decode_attention
 from repro.models.layers import rms_norm

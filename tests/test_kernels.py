@@ -180,6 +180,35 @@ def test_dispatch_combine_rows(t, n_rows, d, k):
                                atol=1e-5, rtol=1e-5)
 
 
+def test_dispatch_combine_rows_nonfinite_reach():
+    """The one-hot gathers match the oracles only on finite inputs.  A NaN
+    at (row, feature c) of the source reaches feature c of EVERY output row,
+    empty rows included: each output tile streams every source tile, and
+    0 * NaN is NaN.  The other features stay exact.  (The oracles' gather
+    keeps the NaN in the one row that selects it.)"""
+    from repro.kernels.dispatch import combine_rows, dispatch_rows
+    t, n_rows, d, c = 32, 24, 8, 3
+    x = jax.random.normal(keys(1)[0], (t, d)).at[5, c].set(jnp.nan)
+    src = jnp.asarray([i + 6 if i % 4 else -1 for i in range(n_rows)],
+                      jnp.int32)                    # token 5 unselected
+    out = np.asarray(dispatch_rows(x, src, block_rows=8, block_src=16))
+    want = np.asarray(ref.ref_dispatch_rows(x, src))
+    assert np.isfinite(want).all()
+    assert np.isnan(out[:, c]).all()
+    others = np.arange(d) != c
+    np.testing.assert_array_equal(out[:, others], want[:, others])
+
+    rows = jnp.asarray([[i, -1] if i < n_rows else [-1, -1]
+                        for i in range(t)], jnp.int32)
+    buf = x[:n_rows]                                 # NaN in slot row 5
+    w = jnp.ones((t, 2), jnp.float32)
+    y = np.asarray(combine_rows(buf, rows, w, block_t=8, block_rows=8))
+    want = np.asarray(ref.ref_combine_rows(buf, rows, w))
+    assert np.isnan(want[5, c]) and np.isfinite(np.delete(want, 5, 0)).all()
+    assert np.isnan(y[:, c]).all()
+    np.testing.assert_array_equal(y[:, others], want[:, others])
+
+
 @pytest.mark.parametrize("b,s,h,kv,hd", [(1, 64, 2, 2, 32), (2, 128, 4, 2, 32),
                                          (2, 64, 8, 1, 64), (1, 256, 4, 4, 16)])
 @pytest.mark.parametrize("causal,window", [(True, 0), (False, 0), (True, 40)])

@@ -8,8 +8,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.core.moe import MoEParams
+from repro.core.moe import MoEParams, default_mesh
 from repro.optim import reduce as R
 from repro.optim.compression import compress_int8_ef, init_int8_state
 
@@ -17,6 +18,10 @@ from repro.optim.compression import compress_int8_ef, init_int8_state
 def tiny_tree():
     return {"w": jnp.arange(12, dtype=jnp.float32).reshape(3, 4) / 7.0,
             "b": jnp.ones((5,), jnp.float32) * 0.3}
+
+
+def replicated(tree):
+    return jax.tree.map(lambda _: P(), tree)
 
 
 # ---------------------------------------------------------------------------
@@ -42,6 +47,23 @@ def test_n_chunks_for_bytes():
     assert R.n_chunks_for_bytes(g, 999) == 5         # ceil
 
 
+@pytest.mark.parametrize("ep", [1, 2, 4])
+def test_shard_n_chunks_is_per_device(ep):
+    """``partition_bytes`` sizes the micro-ops of each device's own shard:
+    an expert gradient split over ``ep`` devices gives the same micro-op
+    count as the replicated layout of one device's share of it."""
+    mesh = AbstractMesh((2, ep), ("data", "model"))
+    per_dev = {"experts": jax.ShapeDtypeStruct((4, 250), jnp.float32),
+               "dense": jax.ShapeDtypeStruct((500,), jnp.float32)}
+    sharded = {"experts": jax.ShapeDtypeStruct((4 * ep, 250), jnp.float32),
+               "dense": per_dev["dense"]}
+    specs = {"experts": P("model", None), "dense": P()}
+    assert R.shard_n_chunks(mesh, sharded, specs, 1000) == \
+        R.shard_n_chunks(mesh, per_dev, replicated(per_dev), 1000) == 6
+    # the whole global tree would have been cut into more micro-ops
+    assert R.n_chunks_for_bytes(sharded, 1000) == 4 * ep + 2
+
+
 # ---------------------------------------------------------------------------
 # single-device identity (collectives over a size-1 dp axis)
 # ---------------------------------------------------------------------------
@@ -50,7 +72,7 @@ def test_n_chunks_for_bytes():
 def test_schedules_identity_on_default_mesh(schedule):
     g = tiny_tree()
     cfg = R.ReduceConfig(schedule, partition_bytes=16)
-    red, state = R.reduce_gradients(None, g, cfg,
+    red, state = R.reduce_gradients(default_mesh(), g, cfg, replicated(g),
                                     after=jnp.zeros((), jnp.float32))
     assert state is None
     for k in g:
@@ -62,7 +84,7 @@ def test_bf16_compression_roundtrip_close():
     g = tiny_tree()
     cfg = R.ReduceConfig("priority+partition", partition_bytes=16,
                          compression="bf16")
-    red, _ = R.reduce_gradients(None, g, cfg)
+    red, _ = R.reduce_gradients(default_mesh(), g, cfg, replicated(g))
     for k in g:
         np.testing.assert_allclose(np.asarray(red[k]), np.asarray(g[k]),
                                    rtol=1e-2, atol=1e-2)
@@ -72,7 +94,8 @@ def test_bf16_compression_roundtrip_close():
 def test_int8_ef_requires_state():
     cfg = R.ReduceConfig("priority", compression="int8_ef")
     with pytest.raises(ValueError, match="ReduceState"):
-        R.reduce_gradients(None, tiny_tree(), cfg)
+        R.reduce_gradients(default_mesh(), tiny_tree(), cfg,
+                           replicated(tiny_tree()))
 
 
 def test_int8_ef_state_threads_through_reduce():
@@ -80,7 +103,8 @@ def test_int8_ef_state_threads_through_reduce():
     cfg = R.ReduceConfig("priority+partition", partition_bytes=16,
                          compression="int8_ef")
     state = R.init_reduce_state(g, cfg)
-    red, state2 = R.reduce_gradients(None, g, cfg, state=state)
+    red, state2 = R.reduce_gradients(default_mesh(), g, cfg, replicated(g),
+                                     state=state)
     assert jax.tree_util.tree_structure(state) == \
         jax.tree_util.tree_structure(state2)
     # residual became nonzero (quantization error was captured, not lost)
