@@ -179,6 +179,7 @@ def dispatch_rows(x, src_tok, scale=None, *, block_rows: int = 1024,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r_pad, d), jnp.float32),
+        name="dispatch_rows",
         interpret=interpret,
     )(src_tok[:, None], scale.astype(jnp.float32)[:, None], x)
     return out[:r].astype(x.dtype)
@@ -252,6 +253,7 @@ def combine_rows(buf, rows, weights, *, block_t: int = 1024,
         ],
         out_specs=pl.BlockSpec((bt, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t_pad, d), jnp.float32),
+        name="combine_rows",
         interpret=interpret,
     )(rows, weights.astype(jnp.float32), buf)
     return out[:t].astype(buf.dtype)
@@ -338,6 +340,7 @@ def weighted_route(expert_idx, position, cum_weights, slot_of,
         ],
         out_specs=pl.BlockSpec((bt, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t_pad, k), jnp.int32),
+        name="weighted_route",
         interpret=interpret,
     )(expert_idx.astype(jnp.int32), position.astype(jnp.int32),
       cum_weights.astype(jnp.int32), slot_of.astype(jnp.int32))

@@ -81,6 +81,7 @@ def grouped_ffn(x, wi, wu, wo, *, ffn_type: str = "swiglu",
         ],
         out_specs=pl.BlockSpec((1, bt, d), lambda e_, t_, f_: (e_, t_, 0)),
         out_shape=jax.ShapeDtypeStruct((e, t_pad, d), jnp.float32),
+        name="grouped_ffn",
         interpret=interpret,
     )(x, wi, wu, wo)
     return out[:, :t].astype(x.dtype)
@@ -135,6 +136,7 @@ def grouped_matmul(a, b, *, block_m: int = 256, block_n: int = 512,
         out_specs=pl.BlockSpec((1, bm, bn),
                                lambda e_, m_, n_, k_: (e_, m_, n_)),
         out_shape=jax.ShapeDtypeStruct((e, m_pad, n_pad), jnp.float32),
+        name="grouped_matmul",
         interpret=interpret,
     )(a, b)
     return out[:, :m, :n]

@@ -94,6 +94,7 @@ def topk_gating_fused(logits_or_x, k: int = 2, *, router=None,
             jax.ShapeDtypeStruct((t_pad, k), jnp.float32),
             jax.ShapeDtypeStruct((t_pad, e), jnp.float32),
         ),
+        name="topk_gating_fused",
         interpret=interpret,
     )(*args)
     return idx[:t], w[:t], probs[:t]
@@ -187,6 +188,7 @@ def topk_positions(expert_idx, n_experts: int, *, block_t: int = 512,
             jax.ShapeDtypeStruct((t_pad, k), jnp.int32),
             jax.ShapeDtypeStruct((SUBLANE, e_pad), jnp.int32),
         ),
+        name="topk_positions",
         interpret=interpret,
     )(expert_idx.astype(jnp.int32))
     return pos[:t]

@@ -8,9 +8,9 @@ is the first-class home for producing the same breakdown here:
                  (open in Perfetto) and a no-op disabled fast path;
   ``metrics``  — counters / gauges / fixed-bucket histograms with
                  Prometheus-text and JSON snapshot export;
-  ``profiler`` — guarded ``jax.profiler`` trace sessions plus the
-                 overlap-phase attribution that turns "fraction of a2a
-                 hidden" into a trace-queryable quantity.
+  ``profiler`` — guarded ``jax.profiler`` trace sessions; while the
+                 tracer is enabled its spans appear in such a device
+                 trace as ``repro.*`` host events.
 
 ``ObsContext`` bundles one tracer + one registry; the serving stack shares
 a single context (``MoEServer`` owns one, ``ServingEngine`` inherits or
@@ -26,8 +26,7 @@ from dataclasses import dataclass
 from repro.obs import tracer as tracer_mod
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                parse_prometheus)
-from repro.obs.profiler import (StepProfiler, attribute_overlap,
-                                hidden_fraction, trace_session)
+from repro.obs.profiler import StepProfiler, trace_session
 from repro.obs.tracer import (NOOP, Span, Tracer, check_span_tree,
                               to_chrome, to_json, tree_from_chrome)
 
@@ -35,7 +34,6 @@ __all__ = [
     "ObsContext", "Tracer", "Span", "NOOP", "MetricsRegistry", "Counter",
     "Gauge", "Histogram", "parse_prometheus", "to_json", "to_chrome",
     "tree_from_chrome", "check_span_tree", "trace_session", "StepProfiler",
-    "attribute_overlap", "hidden_fraction",
 ]
 
 

@@ -19,6 +19,14 @@ stopwatches the runtime used to carry): the elapsed ``dt`` is functional —
 service-time stamps and the phase-2 watchdog depend on it — so it is
 measured in both modes, and only the span recording is gated.
 
+While the tracer is enabled, every stack span and every recorded
+``timed`` span also opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<span name>`` for its duration, so a device trace taken meanwhile
+(``jax.profiler.start_trace``) holds the program's spans on the host
+plane, on the same clock as the device ops.  Explicit-timestamp spans
+(``add``, ``begin``) stay in memory only: their interval is not the
+moment they are recorded.
+
 Exporters: ``to_json`` (lossless nested tree, what the invariant validator
 consumes) and ``to_chrome`` (Chrome ``trace_event`` JSON — open in Perfetto
 via ui.perfetto.dev or chrome://tracing; each root span tree gets its own
@@ -32,6 +40,8 @@ import json
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "NOOP", "to_json", "to_chrome",
            "tree_from_chrome", "check_span_tree"]
@@ -121,38 +131,36 @@ NOOP = _Noop()
 
 class _ActiveSpan:
     """Context manager for stack-nested spans (enabled tracer only)."""
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
         self.span = Span(name, 0.0, attrs=attrs)
+        self._ann = None
 
     def __enter__(self) -> Span:
         tr = self._tracer
-        sp = self.span
-        sp.start = tr.clock()
-        if tr._stack:
-            tr._stack[-1].children.append(sp)
-        else:
-            tr._add_root(sp)
-        tr._stack.append(sp)
-        return sp
+        self._ann = tr._annotate(self.span.name)
+        self.span.start = tr.clock()
+        tr._push(self.span)
+        return self.span
 
     def __exit__(self, *exc):
         tr = self._tracer
-        sp = tr._stack.pop()
-        sp.end = tr.clock()
+        tr._stack.pop().end = tr.clock()
+        self._ann.__exit__(None, None, None)
         return False
 
 
 class _Timed:
     """Always-on stopwatch; records a span only when the tracer is enabled.
     Use where the measured ``dt`` is functional (service-time stamps, the
-    phase-2 watchdog), so disabling tracing cannot change behavior.
+    phase-2 watchdog), so disabling tracing cannot change behavior.  A
+    recorded span is a stack span: spans opened inside nest under it.
     ``record=False`` keeps just the stopwatch — for call sites that lay
     their own explicit-timestamp spans out afterwards (engine step phases
-    live on the virtual clock, not the wall clock being measured here)."""
-    __slots__ = ("_tracer", "_name", "_attrs", "_record", "t0", "dt")
+    on the virtual clock of a replay, not the wall clock measured here)."""
+    __slots__ = ("_tracer", "_name", "_attrs", "_record", "_ann", "t0", "dt")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict,
                  record: bool = True):
@@ -160,18 +168,26 @@ class _Timed:
         self._name = name
         self._attrs = attrs
         self._record = record
+        self._ann = None
         self.t0 = 0.0
         self.dt = 0.0
 
     def __enter__(self) -> "_Timed":
-        self.t0 = self._tracer.clock()
+        tr = self._tracer
+        if self._record and tr.enabled:
+            self._ann = tr._annotate(self._name)
+        self.t0 = tr.clock()
+        if self._ann is not None:
+            tr._push(Span(self._name, self.t0, attrs=self._attrs))
         return self
 
     def __exit__(self, *exc):
         tr = self._tracer
         self.dt = tr.clock() - self.t0
-        if self._record and tr.enabled:
-            tr.add(self._name, self.t0, self.t0 + self.dt, **self._attrs)
+        if self._ann is not None:
+            tr._stack.pop().end = self.t0 + self.dt
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         return False
 
 
@@ -220,6 +236,21 @@ class Tracer:
         else:
             self._add_root(sp)
         return sp
+
+    def _push(self, sp: Span) -> None:
+        """Open a stack span: nest it under the innermost open one."""
+        if self._stack:
+            self._stack[-1].children.append(sp)
+        else:
+            self._add_root(sp)
+        self._stack.append(sp)
+
+    @staticmethod
+    def _annotate(name: str) -> TraceAnnotation:
+        """The span's event in a device trace, if one is being taken."""
+        ann = TraceAnnotation("repro." + name)
+        ann.__enter__()
+        return ann
 
     def _add_root(self, sp: Span) -> None:
         if len(self.roots) >= self._max_roots:

@@ -44,7 +44,7 @@ import numpy as np
 from repro.models.attention import KVCache
 from repro.models.lm import LMCache
 from repro.obs import ObsContext
-from repro.obs.tracer import Span
+from repro.obs.tracer import NOOP, Span
 from repro.runtime.server import LayerStats, MoEServer
 
 
@@ -377,36 +377,42 @@ class ServingEngine:
 
         self._step_stats = []
         tr = self.obs.tracer
+        wall = now is None
         # Three measured service phases (the TTFT decomposition): time spent
         # behind the decode batch is queueing, the prefill forward is
         # prefill, and slot insertion / first-token argmax is insert.  The
-        # stopwatches always run (their sum is the service-time stamp);
-        # span recording rides on the explicit-timestamp layout below so
-        # spans land on the SAME clock as completions (virtual in replay).
-        with tr.timed("decode", record=False) as sw_dec:
-            dec_res = self._run_decodes(decodes) if decodes else None
-        with tr.timed("prefill", record=False) as sw_pre:
-            pre_parts = self._run_prefills(prefills) if prefills else []
-        n_tokens = len(decodes) + sum(r.tokens.shape[0] for r in prefills)
-        extra = 0.0
-        if now is not None and self.service_model is not None:
-            extra = float(self.service_model(self._step_stats, n_tokens))
+        # stopwatches always run (their sum is the service-time stamp).  On
+        # the wall clock the step and its phases are stack spans, so the
+        # server's spans nest under the phase that ran them; in a replay
+        # ``_trace_step`` lays them out afterwards on the virtual clock,
+        # the clock the completions are stamped on.
+        with (tr.span("engine.step", step=self.step_idx,
+                      decodes=len(decodes), prefills=len(prefills))
+              if wall else NOOP):
+            with tr.timed("engine.decode", record=wall) as sw_dec:
+                dec_res = self._run_decodes(decodes) if decodes else None
+            with tr.timed("engine.prefill", record=wall) as sw_pre:
+                pre_parts = self._run_prefills(prefills) if prefills else []
+            n_tokens = len(decodes) + sum(r.tokens.shape[0] for r in prefills)
+            extra = 0.0
+            if not wall and self.service_model is not None:
+                extra = float(self.service_model(self._step_stats, n_tokens))
 
-        # Finish with a NaN placeholder stamp while the insert phase is
-        # still being measured (its wall time is part of the service that
-        # determines the stamp), then patch every stamp minted this step.
-        pending = float("nan")
-        out: List[RequestResult] = []
-        with tr.timed("insert", record=False) as sw_ins:
-            if dec_res is not None:
-                out.extend(self._finish_decodes(decodes, dec_res, pending))
-            for group, res in pre_parts:
-                out.extend(self._finish_prefills(group, res, pending))
-        service = sw_dec.dt + sw_pre.dt + sw_ins.dt
-        if now is None:
-            completion = self.clock()
-        else:
-            completion = now + service * time_scale + extra
+            # Finish with a NaN placeholder stamp while the insert phase is
+            # still being measured (its wall time is part of the service that
+            # determines the stamp), then patch every stamp minted this step.
+            pending = float("nan")
+            out: List[RequestResult] = []
+            with tr.timed("engine.finish", record=wall) as sw_ins:
+                if dec_res is not None:
+                    out.extend(self._finish_decodes(decodes, dec_res, pending))
+                for group, res in pre_parts:
+                    out.extend(self._finish_prefills(group, res, pending))
+            service = sw_dec.dt + sw_pre.dt + sw_ins.dt
+            if wall:
+                completion = self.clock()
+            else:
+                completion = now + service * time_scale + extra
         self.last_step_end = completion
         for r in out:
             r.completion = completion
@@ -415,9 +421,10 @@ class ServingEngine:
         for slot in self._active.values():
             if slot.ttft != slot.ttft:
                 slot.ttft = completion
-        scale = 1.0 if now is None else time_scale
+        scale = 1.0 if wall else time_scale
         self._observe_step(t_now, completion, scale, extra,
-                           (sw_dec.dt, sw_pre.dt), decodes, pre_parts, out)
+                           (sw_dec.dt, sw_pre.dt), decodes, pre_parts, out,
+                           wall)
         if self.scheduler is not None:
             # between micro-batches: feed telemetry, maybe publish plans —
             # they apply from the NEXT step, never mid-batch.  The injector
@@ -431,7 +438,7 @@ class ServingEngine:
 
     # --- observability ------------------------------------------------------
     def _observe_step(self, t_now, completion, scale, extra, walls,
-                      decodes, pre_parts, out) -> None:
+                      decodes, pre_parts, out, wall) -> None:
         """Publish the step into the obs context: registry metrics always,
         span trees only when the tracer is enabled.  Phase boundaries are
         laid out on the completion clock (virtual during replay):
@@ -467,19 +474,21 @@ class ServingEngine:
             met.counter("engine_requests_completed_total").inc(len(out))
         if self.obs.tracer.enabled:
             self._trace_step(t_now, t_dec_end, t_pre_end, completion,
-                             decodes, prefilled, out)
+                             decodes, prefilled, out, wall)
 
     def _trace_step(self, t_now, t_dec_end, t_pre_end, completion,
-                    decodes, prefilled, out) -> None:
-        """Span trees for one step: an ``engine.step`` root with the three
-        phase children, plus per-request lifecycle updates (decode-step
-        ticks, the queued→prefill→insert TTFT decomposition, completion)."""
+                    decodes, prefilled, out, wall) -> None:
+        """Per-request lifecycle updates for one step (decode-step ticks,
+        the queued→prefill→insert TTFT decomposition, completion) and, in
+        a replay, the ``engine.step`` root with its three phase children
+        on the virtual clock (on the wall clock ``step`` recorded it)."""
         tr = self.obs.tracer
-        sp = tr.add("engine.step", t_now, completion, step=self.step_idx,
-                    decodes=len(decodes), prefills=len(prefilled))
-        sp.child("decode", t_now, t_dec_end, n=len(decodes))
-        sp.child("prefill", t_dec_end, t_pre_end, n=len(prefilled))
-        sp.child("insert", t_pre_end, completion)
+        if not wall:
+            sp = tr.add("engine.step", t_now, completion, step=self.step_idx,
+                        decodes=len(decodes), prefills=len(prefilled))
+            sp.child("decode", t_now, t_dec_end, n=len(decodes))
+            sp.child("prefill", t_dec_end, t_pre_end, n=len(prefilled))
+            sp.child("insert", t_pre_end, completion)
         for slot in decodes:
             root = self._req_spans.get(slot.rid)
             if root is not None:
@@ -511,6 +520,25 @@ class ServingEngine:
             cache = self._dec_batch[1]       # pos already advanced inside
             b = cache.kv.k.shape[2]
         else:
+            cache = self._stack_slot_caches(slots)
+            b = cache.kv.k.shape[2]
+        tokens = np.zeros((b,), np.int64)
+        path = np.zeros((b,), np.int64)
+        valid = np.zeros((b,), bool)
+        for i, s in enumerate(slots):
+            tokens[i] = s.gen_tokens[-1]
+            path[i] = s.path_scalar
+            valid[i] = True
+        res = self.server.decode_batch(tokens, cache, path, valid=valid)
+        self._record_stats(res.stats)
+        self._dec_batch = (rids, res.cache)
+        return res
+
+    def _stack_slot_caches(self, slots: List[DecodeSlot]) -> LMCache:
+        """A decode batch's cache when its membership changed: every
+        slot's cache padded to the longest and stacked, plus zero rows up
+        to the row bucket."""
+        with self.obs.tracer.span("engine.kv_assemble"):
             b_real = len(slots)
             b = self._bucket_rows(b_real) if self.ecfg.pad_to_pow2 else b_real
             s_max = max(s.cap for s in slots)
@@ -533,18 +561,7 @@ class ServingEngine:
             pos = np.zeros((b,), np.int32)
             for i, s in enumerate(slots):
                 pos[i] = s.pos
-            cache = LMCache(kv, None, None, jnp.asarray(pos))
-        tokens = np.zeros((b,), np.int64)
-        path = np.zeros((b,), np.int64)
-        valid = np.zeros((b,), bool)
-        for i, s in enumerate(slots):
-            tokens[i] = s.gen_tokens[-1]
-            path[i] = s.path_scalar
-            valid[i] = True
-        res = self.server.decode_batch(tokens, cache, path, valid=valid)
-        self._record_stats(res.stats)
-        self._dec_batch = (rids, res.cache)
-        return res
+            return LMCache(kv, None, None, jnp.asarray(pos))
 
     def _finish_decodes(self, slots, res, completion) -> List[RequestResult]:
         out = []

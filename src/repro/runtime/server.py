@@ -504,10 +504,10 @@ class MoEServer:
 
             with tr.span("gate"):
                 _, idx = self._gate(gp.moe.router, h2)
-                top1 = np.asarray(idx[:, 0])
-                actual = np.bincount(top1, weights=valid.astype(np.float64),
-                                     minlength=cfg.moe.n_experts)
-                actual = actual / max(actual.sum(), 1.0)
+            top1 = self._to_host("sync.top1", idx[:, 0])
+            actual = np.bincount(top1, weights=valid.astype(np.float64),
+                                 minlength=cfg.moe.n_experts)
+            actual = actual / max(actual.sum(), 1.0)
 
             plan, finetuned, accurate, reused = self._plan_layer(li, est,
                                                                  actual)
@@ -521,12 +521,13 @@ class MoEServer:
                 y = self._dispatch(gp.moe, h2, se, ro, nr, rw,
                                    min_replicas=min_rep, cap=cap)
 
+            with tr.span("server.mirror"):
                 # host mirror of the replica split: realized valid-token
                 # count per (device, sub-slot) — what the telemetry
                 # bus/controller observes as post-routing imbalance
                 rep_load = replica_token_counts(
-                    np.asarray(idx), self._host_plan(plan), cap,
-                    slot_capacity(cap, min_rep), valid=valid,
+                    self._to_host("sync.top1", idx), self._host_plan(plan),
+                    cap, slot_capacity(cap, min_rep), valid=valid,
                     dp_shards=dp_shard_count(self.mesh, h2.shape[0]),
                     route_mode=scfg.route_mode)
             lsp.set(finetuned=finetuned, reused=reused, accurate=accurate)
@@ -545,6 +546,15 @@ class MoEServer:
                           replica_load=rep_load)
         return y, top1, stat
 
+    def _to_host(self, name: str, x) -> np.ndarray:
+        """Read a device array on the host: the host waits here until the
+        device has computed ``x``.  Every such read on the serving path
+        goes through here, as one ``sync.*`` span (``name``) and one
+        ``server_host_syncs_total``."""
+        self.obs.metrics.counter("server_host_syncs_total").inc()
+        with self.obs.tracer.span(name):
+            return np.asarray(x)
+
     def _plan_device(self, plan: PlacementPlan):
         """Device-resident plan arrays, cached per plan object — the
         PlanCache keeps plan identity stable across batches/steps, so the
@@ -562,11 +572,12 @@ class MoEServer:
                 host_rw = np.asarray(mask_dead_route_weights(
                     host_rw, plan.replica_of, plan.max_pack,
                     self.dead_devices, xp=np), np.float32)
-            ent = (plan, jnp.asarray(plan.slot_expert),
-                   jnp.asarray(plan.replica_of), jnp.asarray(plan.n_replicas),
-                   jnp.asarray(host_rw),
-                   PlanArrays(plan.slot_expert, plan.replica_of,
-                              plan.n_replicas, host_rw))
+            with self.obs.tracer.span("plan.upload"):
+                ent = (plan, jnp.asarray(plan.slot_expert),
+                       jnp.asarray(plan.replica_of),
+                       jnp.asarray(plan.n_replicas), jnp.asarray(host_rw),
+                       PlanArrays(plan.slot_expert, plan.replica_of,
+                                  plan.n_replicas, host_rw))
             self._plan_arrays[id(plan)] = ent
         return ent[1], ent[2], ent[3], ent[4]
 
@@ -646,12 +657,14 @@ class MoEServer:
         ks: List[jax.Array] = []
         vs: List[jax.Array] = []
         n_groups = cfg.n_layers // self.every
+        tr = self.obs.tracer
         moe_layer_idx = 0
         for g in range(n_groups):
             gp = self._group_params(g)
             ks_g, vs_g = [], []
             for j in range(self.every):
-                x, k_j, v_j = attn(gp, j, x)
+                with tr.span("server.attn"):
+                    x, k_j, v_j = attn(gp, j, x)
                 if k_j is not None:
                     ks_g.append(k_j)
                     vs_g.append(v_j)
@@ -662,7 +675,8 @@ class MoEServer:
                                          None, gp.ffn,
                                          is_leaf=lambda a: a is None) \
                         if gp.ffn is not None and gp.ffn.w_in.ndim > 2 else gp.ffn
-                    x = x + self._ffn(ffn_p, h)
+                    with tr.span("server.ffn"):
+                        x = x + self._ffn(ffn_p, h)
                     continue
                 h2 = h.reshape(t, d)
                 y, top1, stat = self._serve_moe(moe_layer_idx, gp, h2, valid,
@@ -708,10 +722,12 @@ class MoEServer:
         x, stats, path_ids, ks, vs = self._walk_stack(
             x, attn=attn, valid=valid, path_ids=path_ids,
             has_state=False, shape=(b, s))
-        x = rms_norm(x, self._cparams.final_norm, cfg.norm_eps)
-        last = np.maximum(lengths - 1, 0)
-        x_last = np.asarray(x)[np.arange(b), last]
-        logits = np.asarray(jnp.asarray(x_last) @ self._w_unembed)
+        with self.obs.tracer.span("server.head"):
+            x = rms_norm(x, self._cparams.final_norm, cfg.norm_eps)
+            last = np.maximum(lengths - 1, 0)
+            x_last = self._to_host("sync.hidden", x)[np.arange(b), last]
+            logits = self._to_host("sync.logits",
+                                   jnp.asarray(x_last) @ self._w_unembed)
         cache = None
         if cache_len:
             kv = KVCache(jnp.stack(ks), jnp.stack(vs))
@@ -757,8 +773,9 @@ class MoEServer:
         x, stats, path_ids, ks, vs = self._walk_stack(
             x, attn=attn, valid=valid, path_ids=path_ids,
             has_state=True, shape=(b, 1))
-        x = rms_norm(x, self._cparams.final_norm, cfg.norm_eps)
-        logits = np.asarray(x[:, 0] @ self._w_unembed)
+        with self.obs.tracer.span("server.head"):
+            x = rms_norm(x, self._cparams.final_norm, cfg.norm_eps)
+            logits = self._to_host("sync.logits", x[:, 0] @ self._w_unembed)
         new_cache = LMCache(KVCache(jnp.stack(ks), jnp.stack(vs)), None, None,
                             pos + 1)
         return DecodeResult(np.asarray(logits), stats, path_ids, new_cache)

@@ -3,10 +3,11 @@ TTFT = queue + prefill + insert identity on a real engine run (including
 the Chrome-trace export round-trip the validator gates in CI), the
 disabled fast path (no span allocation, bounded overhead), histogram
 quantile accuracy against exact quantiles, the Prometheus text
-round-trip, the admission ledger read back through the metrics view, and
-the overlap attribution replay against BENCH_schedules.json."""
+round-trip, the admission ledger read back through the metrics view, the
+device-trace annotation of every recorded span, and the wall-clock engine
+step: its span tree, and every device->host read of the serving path
+inside a ``sync.*`` span counted by ``server_host_syncs_total``."""
 import json
-import os
 import time
 
 import numpy as np
@@ -15,17 +16,16 @@ import pytest
 from repro.configs import get_config
 from repro.core.popularity import PathProfile
 from repro.obs import (Histogram, MetricsRegistry, NOOP, ObsContext, Tracer,
-                       attribute_overlap, check_span_tree, hidden_fraction,
-                       parse_prometheus, to_chrome, tree_from_chrome)
+                       check_span_tree, parse_prometheus, tree_from_chrome)
+from repro.obs import tracer as tracer_mod
 from repro.obs.__main__ import check_ledger, check_request_ttft
 from repro.obs.__main__ import main as obs_validate
 from repro.models import lm as lm_mod
 from repro.runtime.engine import EngineConfig, ServingEngine, simulate
+from repro.runtime import engine as engine_mod
+from repro.runtime import server as server_mod
 from repro.runtime.server import MoEServer, ServerConfig
 from repro.sched import get_trace
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 # --- tracer core ------------------------------------------------------------
 
@@ -258,7 +258,7 @@ def test_disabled_tracing_overhead_within_2pct(untraced_run):
     step_h = obs.metrics.get("engine_step_service_s")
     assert step_h is not None and step_h.count > 0
     mean_step = step_h.sum / step_h.count
-    calls_per_step = 64          # ~25 in reality (engine 3 + 5/MoE layer)
+    calls_per_step = 64          # at most 51 on this stack (11 a MoE layer)
     assert per_call * calls_per_step < 0.02 * mean_step, \
         (per_call, mean_step)
 
@@ -283,34 +283,196 @@ def test_admission_ledger_closes_through_metrics_view():
     assert completed == len(results)
 
 
-# --- overlap attribution ----------------------------------------------------
+# --- device-trace annotations ---------------------------------------------
 
-def test_overlap_attribution_matches_bench_json():
-    """hidden_fraction recomputed FROM THE TRACE must equal each
-    BENCH_schedules.json overlap row's a2a_hidden_frac — and survive a
-    Chrome export round-trip (the acceptance identity of the obs layer)."""
-    with open(os.path.join(REPO_ROOT, "BENCH_schedules.json")) as f:
-        rows = json.load(f)["overlap"]
-    assert rows, "BENCH_schedules.json has no overlap rows"
-    tr = Tracer(enabled=True)
-    roots = attribute_overlap(tr, rows)
-    assert len(roots) == len(rows)
-    assert check_span_tree(tr.roots) == []
-    for root, row in zip(roots, rows):
-        # rows store values printed at 0.1us so allow that quantization
-        assert abs(hidden_fraction(root) - row["a2a_hidden_frac"]) < 0.01
-    trees = tree_from_chrome(to_chrome(tr))
-    assert len(trees) == len(rows)
-    for tree, row in zip(trees, rows):
-        assert abs(hidden_fraction(tree) - row["a2a_hidden_frac"]) < 0.01
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs open/close."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+        return False
 
 
-def test_attribution_on_a_disabled_tracer_is_empty():
-    tr = Tracer(enabled=False)
-    rows = [{"variant": "pipelined", "chunks_requested": 2,
-             "chunks_chosen": 2, "us_per_call": 150.0, "serial_us": 200.0,
-             "a2a_us": 100.0, "a2a_hidden_frac": 0.5}]
-    roots = attribute_overlap(tr, rows)
-    assert tr.roots == []
-    assert all(r is NOOP for r in roots)
-    assert hidden_fraction(NOOP) == 0.0
+@pytest.mark.parametrize("enabled", [True, False])
+def test_recorded_spans_open_one_trace_annotation_each(monkeypatch, enabled):
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", _FakeAnnotation)
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    tr = Tracer(enabled=enabled)
+    with tr.span("outer"):
+        with tr.timed("watch") as sw:
+            with tr.span("inner"):
+                pass
+        with tr.timed("quiet", record=False):
+            pass
+        tr.add("explicit", 0.0, 1.0)
+    tr.begin("manual", start=0.0).end_at(1.0)
+    assert sw.dt >= 0.0                       # the stopwatch always runs
+    if not enabled:
+        assert _FakeAnnotation.log == []
+        return
+    # stack and recorded timed spans only, opened and closed as they nest
+    assert _FakeAnnotation.log == [
+        ("open", "repro.outer"), ("open", "repro.watch"),
+        ("open", "repro.inner"), ("close", "repro.inner"),
+        ("close", "repro.watch"), ("close", "repro.outer")]
+    # a recorded timed span is a stack span: what opens inside nests in it
+    outer = tr.roots[0]
+    assert [c.name for c in outer.children] == ["watch", "explicit"]
+    watch = outer.children[0]
+    assert [c.name for c in watch.children] == ["inner"]
+    assert watch.duration == pytest.approx(sw.dt)
+
+
+# --- the wall-clock engine step -------------------------------------------
+
+class _ReadLog:
+    """Every device->host read of the serving modules, by the innermost
+    open span at the time.  Reads are caught where numpy meets a device
+    array in ``runtime.server`` / ``runtime.engine`` and, for the scalar
+    and ``__array__`` conversions, in ``jax.Array``'s host value."""
+
+    def __init__(self, monkeypatch, tracer):
+        import types
+
+        import jax
+        from jax._src import array as jax_array
+        self.tracer = tracer
+        self.sites = []
+        self._inside = False
+        log = self
+
+        def where():
+            stack = log.tracer._stack
+            return stack[-1].name if stack else None
+
+        class _Numpy(types.ModuleType):
+            def __getattr__(self, name):
+                attr = getattr(np, name)
+                if isinstance(attr, (type, types.ModuleType)) or \
+                        not callable(attr):
+                    return attr
+
+                def call(*args, **kwargs):
+                    dev = any(isinstance(a, jax.Array) for a in
+                              list(args) + list(kwargs.values()))
+                    if dev:
+                        log.sites.append(where())
+                    log._inside = dev
+                    try:
+                        return attr(*args, **kwargs)
+                    finally:
+                        log._inside = False
+                return call
+
+        host_value = jax_array.ArrayImpl._value
+
+        def value(arr):
+            if not log._inside:
+                log.sites.append(where())
+            return host_value.fget(arr)
+
+        monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(value))
+        for mod in (server_mod, engine_mod):
+            monkeypatch.setattr(mod, "np", _Numpy("numpy"))
+
+    def take(self):
+        out, self.sites = self.sites, []
+        return out
+
+
+@pytest.fixture(scope="module")
+def wall_run(tmp_path_factory):
+    """A traced engine driven on the wall clock (``step()``), as a
+    deployment serves: two generating requests, prefill then decodes."""
+    obs = ObsContext.enabled()
+    cfg, eng = _smoke_stack(obs)
+    rng = np.random.RandomState(11)
+    for _ in range(2):
+        eng.submit(rng.randint(0, cfg.vocab_size, (8,)), max_new_tokens=4)
+    results = eng.run()
+    out = str(tmp_path_factory.mktemp("obs_wall"))
+    obs.export(out)
+    return cfg, obs, eng, results, out
+
+
+def test_wall_step_is_one_stack_tree_per_step(wall_run):
+    _cfg, obs, _eng, results, out = wall_run
+    assert len(results) == 2
+    roots = obs.tracer.roots
+    assert check_span_tree(roots) == []
+    steps = [r for r in roots if r.name == "engine.step"]
+    assert len(steps) == obs.metrics.value("engine_steps_total") == 4
+    # no replay-layout duplicates, and the server spans nest in the phases
+    assert {r.name for r in roots} == {"engine.step", "request"}
+    for st in steps:
+        assert {c.name for c in st.children} == {
+            "engine.decode", "engine.prefill", "engine.finish"}
+        for ph in st.children:
+            names = {c.name for c in ph.children}
+            if ph.name == "engine.finish" or not ph.duration:
+                continue
+            assert "server.layer" in names or not names
+    decode = [ph for st in steps for ph in st.children
+              if ph.name == "engine.decode" and ph.children]
+    assert decode
+    names = {sp.name for ph in decode for sp in ph.walk()}
+    assert {"server.attn", "server.layer", "gate", "sync.top1", "dispatch",
+            "server.mirror", "server.head", "sync.logits"} <= names
+    assert obs_validate(["validate", "--trace-dir", out,
+                         "--require-requests", "2"]) == 0
+
+
+def test_wall_run_keeps_ttft_decomposition(wall_run):
+    _cfg, obs, _eng, results, _out = wall_run
+    errs, n = check_request_ttft(obs.tracer.roots, tol=1e-6)
+    assert errs == [] and n == 2
+    by_rid = {r.rid: r for r in results}
+    for root in obs.tracer.roots:
+        if root.name == "request":
+            r = by_rid[root.attrs["rid"]]
+            assert root.attrs["ttft_s"] == pytest.approx(r.ttft_latency,
+                                                         abs=1e-9)
+
+
+def test_sync_spans_match_the_sync_counter(wall_run):
+    cfg, obs, _eng, _results, _out = wall_run
+    syncs = [sp for r in obs.tracer.roots for sp in r.walk()
+             if sp.name.startswith("sync.")]
+    assert len(syncs) == obs.metrics.value("server_host_syncs_total") > 0
+    # one prefill (2 * L + hidden + logits), then 3 decodes (2 * L + 1)
+    n_moe = cfg.n_moe_layers
+    assert len(syncs) == (2 * n_moe + 2) + 3 * (2 * n_moe + 1)
+
+
+def test_device_reads_of_a_step_are_the_listed_sync_sites(monkeypatch):
+    """Every device->host read of an engine step happens inside a
+    ``sync.*`` span, at the sites PERF.md lists: per MoE layer the gate's
+    first choice and then all of ``idx`` (``sync.top1``), per forward the
+    logits (``sync.logits``), and a prefill's hidden state
+    (``sync.hidden``).  Spans add none."""
+    obs = ObsContext.enabled()
+    cfg, eng = _smoke_stack(obs)
+    rng = np.random.RandomState(12)
+    reads = _ReadLog(monkeypatch, obs.tracer)
+    n_moe = cfg.n_moe_layers
+    met = obs.metrics
+    for _ in range(2):
+        eng.submit(rng.randint(0, cfg.vocab_size, (8,)), max_new_tokens=4)
+    want = {"prefill": {"sync.top1": 2 * n_moe, "sync.hidden": 1,
+                        "sync.logits": 1},
+            "decode": {"sync.top1": 2 * n_moe, "sync.logits": 1}}
+    for kind in ("prefill", "decode", "decode"):
+        before = met.value("server_host_syncs_total")
+        eng.step()
+        sites = reads.take()
+        got = {name: sites.count(name) for name in set(sites)}
+        assert got == want[kind], (kind, sites)
+        assert met.value("server_host_syncs_total") - before == len(sites)

@@ -10,6 +10,7 @@ worker collects the same tests and only the worker running this file
 loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,5 +99,9 @@ def _case(name, sharding):
 def test_kernel_compiles_natively_for_v5e(name, one_chip):
     fn, args = _case(name, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
-    # the kernel is in the program as a Mosaic custom call, not a fallback
-    assert "tpu_custom_call" in compiled.as_text()
+    # the kernel is in the program as a Mosaic custom call, not a fallback,
+    # and the call carries the kernel's name (what a device trace shows)
+    calls = [ln.split("=")[0].strip() for ln in compiled.as_text().splitlines()
+             if "custom-call(" in ln and "tpu_custom_call" in ln]
+    assert calls and all(re.fullmatch(rf"%{name}(\.\d+)?", c)
+                         for c in calls), calls
