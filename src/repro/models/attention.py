@@ -158,17 +158,54 @@ def attention(mesh, p: AttnParams, x, cfg, positions=None):
 def decode_attention(mesh, p: AttnParams, x, cache: KVCache, pos, cfg):
     """One-token decode.  x: [B, 1, d]; pos: [B] absolute position; the cache
     holds S_max slots (ring-buffered when sliding window is on)."""
-    b = x.shape[0]
     hd = cfg.resolved_head_dim
     q, k_new, v_new = _project_qkv(p, x, cfg.n_heads, cfg.n_kv_heads, hd,
                                    pos[:, None], cfg.rope_theta, cfg.norm_eps)
-    s_max = cache.k.shape[1]
-    slot = pos % s_max if cfg.sliding_window else jnp.minimum(pos, s_max - 1)
+    slot, _ = _decode_slot(pos, cache.k.shape[1], cfg)
     k = jax.vmap(lambda c, kn, i: jax.lax.dynamic_update_slice(
         c, kn, (i, 0, 0)))(cache.k, k_new, slot)
     v = jax.vmap(lambda c, vn, i: jax.lax.dynamic_update_slice(
         c, vn, (i, 0, 0)))(cache.v, v_new, slot)
+    y = _decode_attend(q, k, v, pos, slot, cfg, x.dtype)
+    return y @ p.wo, KVCache(k, v)
 
+
+def decode_attention_rows(p: AttnParams, x, cache: KVCache, pos, cfg):
+    """One-token decode that leaves ``cache`` as it is: the token's K/V
+    take its slot by a select inside the attention, and only the new rows
+    come back.  x: [B, 1, d]; pos: [B].  Returns (y [B, 1, d],
+    k_new [B, KV, hd], v_new [B, KV, hd]); ``write_decode_rows`` puts the
+    rows into a copy of the cache.  Same numbers as ``decode_attention``."""
+    hd = cfg.resolved_head_dim
+    q, k_new, v_new = _project_qkv(p, x, cfg.n_heads, cfg.n_kv_heads, hd,
+                                   pos[:, None], cfg.rope_theta, cfg.norm_eps)
+    slot, hit = _decode_slot(pos, cache.k.shape[1], cfg)
+    k = jnp.where(hit, k_new, cache.k)
+    v = jnp.where(hit, v_new, cache.v)
+    y = _decode_attend(q, k, v, pos, slot, cfg, x.dtype)
+    return y @ p.wo, k_new[:, 0], v_new[:, 0]
+
+
+def write_decode_rows(cache_k, rows, pos, cfg):
+    """Write one decode step's rows into a copy of a stacked cache.
+    cache_k: [..., B, S_cap, KV, hd]; rows: [..., B, KV, hd]; pos: [B]."""
+    _, hit = _decode_slot(pos, cache_k.shape[-3], cfg)
+    return jnp.where(hit, rows[..., None, :, :], cache_k)
+
+
+def _decode_slot(pos, s_max, cfg):
+    """The cache slot [B] a decode token at ``pos`` writes, and its mask
+    [B, S_cap, 1, 1] over the cache's time axis."""
+    slot = pos % s_max if cfg.sliding_window else jnp.minimum(pos, s_max - 1)
+    return slot, (jnp.arange(s_max)[None, :] == slot[:, None])[:, :, None, None]
+
+
+def _decode_attend(q, k, v, pos, slot, cfg, dtype):
+    """Attention of the decode queries q [B, 1, H, hd] over the cache k/v
+    [B, S_cap, KV, hd] that already holds this token; returns
+    [B, 1, H*hd]."""
+    b, s_max = k.shape[0], k.shape[1]
+    hd = q.shape[-1]
     kv = cfg.n_kv_heads
     rep = cfg.n_heads // kv
     kk = jnp.repeat(k, rep, axis=2) if rep > 1 else k
@@ -182,9 +219,9 @@ def decode_attention(mesh, p: AttnParams, x, cache: KVCache, pos, cfg):
     else:
         valid = kpos <= pos[:, None]
     logits = jnp.where(valid[:, None, None, :], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    o = jnp.einsum("bhqk,bkhd->bqhd", probs, vv).reshape(b, 1, cfg.n_heads * hd)
-    return o @ p.wo, KVCache(k, v)
+    probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, vv).reshape(
+        b, 1, cfg.n_heads * hd)
 
 
 def init_kv_cache(cfg, batch, seq_len, dtype=jnp.bfloat16) -> KVCache:

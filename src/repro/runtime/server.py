@@ -7,8 +7,9 @@ Per MoE layer the Server:
            the estimate's top-2k set still matches the popularity the plan
            was built from (PlanCache); only on drift does it re-plan
            (Eq. 1 + FFD replication/packing);
-  gate:    runs the actual gating network (a router matmul; the full MoE
-           dispatch below re-derives the identical gating inside jit);
+  gate:    the layer's block call (below) ends in the actual gating
+           network's top-k, read on the host once (the full MoE dispatch
+           re-derives the identical gating inside jit);
   phase 2: compares top-2k estimated vs actual experts; on deviation,
            re-plans from the actual popularity (blocking — the paper's
            ~23% fine-tune case) and refreshes the cache;
@@ -29,6 +30,13 @@ That per-layer core (``_serve_moe``) backs three entry points:
                      latency-bound decoding regime (§5): tiny batches,
                      popularity skew, per-layer plan-scheduled dispatch.
 
+Each MoE layer of a forward enqueues two compiled programs around the host
+planner: a block call (the previous layer's residual add, the group's
+attention and dense sublayers, the router's top-k) and the dispatch; one
+head call ends the forward (final norm, unembed, the cache it hands on).
+Decode reads the stacked KV cache in place and writes its new rows into a
+copy, so a cache once returned stays valid.
+
 The Server drives real model weights (GroupParams stacks: the paper models,
 mixtral, llama4) and produces exact logits plus per-layer scheduling stats.
 ``runtime.engine`` wraps it in a continuous-batching front end (request
@@ -38,7 +46,6 @@ path + KV state).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 from typing import List, NamedTuple, Optional
 
 import jax
@@ -57,7 +64,8 @@ from repro.core.serving import (PlanArrays, dp_shard_count,
                                 slot_capacity)
 from repro.kernels.ref import router_logits
 from repro.models import lm as lm_mod
-from repro.models.attention import KVCache, attention, decode_attention
+from repro.models.attention import (KVCache, attention,
+                                    decode_attention_rows, write_decode_rows)
 from repro.models.layers import rms_norm
 from repro.models.lm import LMCache
 from repro.obs import ObsContext
@@ -135,20 +143,26 @@ class MoEServer:
         self.every = cfg.moe.every
         self.plan_cache = PlanCache(top_k=scfg.top_k) if scfg.plan_cache \
             else None
-        self._attn = jax.jit(self._attn_fn)
-        self._attn_dec = jax.jit(self._attn_dec_fn)
-        self._gate = jax.jit(self._gate_fn)
+        # a forward is 2 * n_moe + 1 compiled calls: per MoE layer one
+        # block (everything before the planner) and one dispatch (after
+        # it), then one head; nothing runs eagerly in between.  Block and
+        # head round every intermediate to the compute dtype, as the
+        # op-by-op walk they replace did: XLA's default excess precision
+        # would keep fused bf16 intermediates in f32 and move the served
+        # token wherever two logits nearly tie
+        rounded = {"xla_allow_excess_precision": False}
+        self._block = jax.jit(self._block_fn, compiler_options=rounded)
         self._dispatch = jax.jit(self._dispatch_fn,
                                  static_argnames=("min_replicas", "cap"))
-        self._ffn = jax.jit(partial(lm_mod._ffn_apply, ffn_type=cfg.ffn_type,
-                                    mesh=None))
-        # weights are static across requests: cast once, slice layer groups
-        # once, keep the unembed matrix device-resident — incremental decode
-        # calls this machinery once per generated token, so per-call casts
-        # and host matmuls would dominate TPOT
+        self._head = jax.jit(self._head_fn, static_argnames=("cache_len",),
+                             compiler_options=rounded)
+        # weights are static across requests: cast once; the block and head
+        # calls index the stacked params by a device-resident group index,
+        # the dispatch takes one layer's experts, sliced once
         self._cparams = lm_mod.cast_for_compute(cfg, params)
-        self._w_unembed = jnp.asarray(lm_mod.unembed_weight(self._cparams))
-        self._gp_cache: dict = {}
+        self.n_groups = cfg.n_layers // self.every
+        self._gidx = [jnp.asarray(g, jnp.int32) for g in range(self.n_groups)]
+        self._moe_cache: dict = {}
         self._plan_arrays: dict = {}
         # controller-published per-layer plans (repro.sched): while a layer
         # has an override the per-batch planner (phase 1 + phase 2) is
@@ -286,7 +300,7 @@ class MoEServer:
         from repro.core.placement import plan_from_replicas
 
         cfg = self.cfg
-        gp = self._group_params(0)
+        moe_p = self._moe_params(0)
         combos = set()
         for n_valid in sorted(set(int(r) for r in rows)):
             bucket = 1 << (n_valid - 1).bit_length()
@@ -308,34 +322,105 @@ class MoEServer:
             se, ro, nr, rw = self._plan_device(plan)
             h2 = jnp.zeros((bucket, cfg.d_model), jnp.dtype(cfg.dtype))
             jax.block_until_ready(self._dispatch(
-                gp.moe, h2, se, ro, nr, rw,
+                moe_p, h2, se, ro, nr, rw,
                 min_replicas=int(plan.n_replicas.min()), cap=cap))
         return len(combos)
 
-    # --- jitted layer pieces ----------------------------------------------
-    def _attn_fn(self, gp, j, x):
-        """Full-sequence attention block; also returns the K/V projections
-        so prefill can populate the decode cache for free."""
-        a_p = jax.tree.map(lambda a: a[j] if a is not None else None, gp.attn,
-                           is_leaf=lambda a: a is None)
-        h = rms_norm(x, gp.ln1[j], self.cfg.norm_eps)
-        y, kv = attention(None, a_p, h, self.cfg)
-        return x + y, kv.k, kv.v
+    # --- the walk's compiled calls ----------------------------------------
+    def _launch(self, fn, *args, **kwargs):
+        """Enqueue one compiled program of the walk: a block, a dispatch or
+        the head.  Each launch counts once in ``server_program_calls_total``
+        (2 * n_moe + 1 a forward)."""
+        self.obs.metrics.counter("server_program_calls_total").inc()
+        return fn(*args, **kwargs)
 
-    def _attn_dec_fn(self, gp, j, x, k, v, pos):
-        """Single-token attention block against the KV cache.  x: [B,1,d];
-        k/v: [B, S_cap, KV, hd]; pos: [B] absolute positions."""
-        a_p = jax.tree.map(lambda a: a[j] if a is not None else None, gp.attn,
-                           is_leaf=lambda a: a is None)
-        h = rms_norm(x, gp.ln1[j], self.cfg.norm_eps)
-        y, kv = decode_attention(None, a_p, h, KVCache(k, v), pos, self.cfg)
-        return x + y, kv.k, kv.v
+    def _block_fn(self, cp, g, carry, kv, pos):
+        """One MoE layer's device work before Lina's planner, one program
+        for every layer: the previous MoE layer's residual add (the
+        embedding lookup for the first layer), the group's attention and
+        dense FFN sublayers, the MoE sublayer's input norm and the router's
+        top-k.
 
-    def _gate_fn(self, router, h2):
-        logits = router_logits(h2, router)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
+        cp: the compute params, stacked over groups; g: int32 group index;
+        carry: tokens [B, S] (first layer) or the previous MoE layer's
+        (x [B, S, d], h2 [T, d], y [T, d]); kv: None for a full-sequence
+        forward, else the decode step's stacked cache (k, v)
+        [G, every, B, S_cap, KV, hd], read at ``g`` in place; pos: [B]
+        decode positions.  Returns (x, h2, idx [T, top_k], k, v), k/v the
+        group's [every, B, S, KV, hd] over the sequence, or its
+        [every, B, KV, hd] decode rows."""
+        cfg = self.cfg
+        st = cp.stack
+        if isinstance(carry, tuple):
+            x = self._moe_residual(st, g - 1, *carry)
+        else:
+            x = cp.embed[carry].astype(jnp.dtype(cfg.dtype))
+        ks, vs = [], []
+        for j in range(self.every):
+            a_p = jax.tree.map(lambda a: a[g, j], st.attn)
+            h = rms_norm(x, st.ln1[g, j], cfg.norm_eps)
+            if kv is None:
+                y, kv_j = attention(None, a_p, h, cfg)
+                k_j, v_j = kv_j
+            else:
+                y, k_j, v_j = decode_attention_rows(
+                    a_p, h, KVCache(kv.k[g, j], kv.v[g, j]), pos, cfg)
+            x = x + y
+            ks.append(k_j)
+            vs.append(v_j)
+            h = rms_norm(x, st.ln2[g, j], cfg.norm_eps)
+            if j < self.every - 1:
+                x = x + self._dense_ffn(
+                    jax.tree.map(lambda a: a[g, j], st.ffn), h)
+        b, s, d = x.shape
+        h2 = h.reshape(b * s, d)
+        probs = jax.nn.softmax(
+            router_logits(h2, st.moe.router[g]).astype(jnp.float32), -1)
         _, idx = jax.lax.top_k(probs, self.scfg.top_k)
-        return probs, idx.astype(jnp.int32)
+        return x, h2, idx.astype(jnp.int32), jnp.stack(ks), jnp.stack(vs)
+
+    def _head_fn(self, cp, carry, pos, rows, kv, *, cache_len: int):
+        """The device work after the last MoE layer's dispatch, one program
+        a forward: its residual add, the final norm, each row's last valid
+        position, the unembed and the cache the forward hands on.
+
+        pos: [B] valid lengths (full sequence) or decode positions; rows:
+        the block calls' (k, v) per group, or None (scoring: no cache); kv:
+        the decode step's input cache, or None (full sequence); cache_len:
+        the capacity of a captured cache.  Returns (logits [B, V], LMCache
+        or None).  A decode step writes its rows into a copy of ``kv``:
+        callers may read the input cache again."""
+        cfg = self.cfg
+        x = self._moe_residual(cp.stack, self.n_groups - 1, *carry)
+        x = rms_norm(x, cp.final_norm, cfg.norm_eps)
+        if kv is None:
+            x = x[jnp.arange(x.shape[0]), jnp.maximum(pos - 1, 0)]
+        else:
+            x = x[:, 0]
+        logits = x @ lm_mod.unembed_weight(cp)
+        if rows is None:
+            return logits, None
+        k = jnp.stack([r[0] for r in rows])
+        v = jnp.stack([r[1] for r in rows])
+        if kv is not None:
+            kv = KVCache(write_decode_rows(kv.k, k, pos, cfg),
+                         write_decode_rows(kv.v, v, pos, cfg))
+            return logits, LMCache(kv, None, None, pos + 1)
+        pad = ((0, 0),) * 3 + ((0, cache_len - k.shape[3]),) + ((0, 0),) * 2
+        kv = KVCache(jnp.pad(k, pad), jnp.pad(v, pad))
+        return logits, LMCache(kv, None, None, pos)
+
+    def _moe_residual(self, st, g, x, h2, y):
+        """x + the MoE layer of group ``g``'s output (``y`` from the
+        dispatch, plus the shared expert on ``h2`` if the params hold one)."""
+        moe_y = y.reshape(x.shape)
+        if st.shared is not None:
+            shared = jax.tree.map(lambda a: a[g], st.shared)
+            moe_y = moe_y + self._dense_ffn(shared, h2.reshape(x.shape))
+        return x + moe_y
+
+    def _dense_ffn(self, p, h):
+        return lm_mod._ffn_apply(p, h, self.cfg.ffn_type, None)
 
     def _dispatch_fn(self, moe_p, h2, se, ro, nr, rw, *, min_replicas: int,
                      cap: int):
@@ -472,15 +557,17 @@ class MoEServer:
         return plan
 
     # --- the shared per-layer two-phase core -------------------------------
-    def _serve_moe(self, li: int, gp, h2, valid: np.ndarray,
+    def _serve_moe(self, li: int, h2, idx, valid: np.ndarray,
                    path_ids: np.ndarray, has_state: bool):
-        """Phase-1 estimate -> PlanCache lookup -> gate -> phase-2
-        fine-tune on drift -> plan-honoring dispatch, for one MoE layer.
+        """Phase-1 estimate -> PlanCache lookup -> phase-2 fine-tune on
+        drift -> plan-honoring dispatch, for one MoE layer.
 
-        h2: [T, d] hidden states; valid: [T] bool; path_ids: [T] rolling
-        path hashes.  ``has_state`` marks carried path state (incremental
-        decode), which lets early layers use the profile instead of the
-        uniform cold-start estimate.  Returns (y [T, d], top1 [T], stats).
+        h2: [T, d] hidden states and idx: [T, top_k] the gate's choices,
+        both from the layer's block call; valid: [T] bool; path_ids: [T]
+        rolling path hashes.  ``has_state`` marks carried path state
+        (incremental decode), which lets early layers use the profile
+        instead of the uniform cold-start estimate.  Returns (y [T, d],
+        top1 [T], stats).
         """
         cfg, scfg = self.cfg, self.scfg
         tr = self.obs.tracer
@@ -502,9 +589,10 @@ class MoEServer:
                     est = self.profile.estimate_popularity(
                         li, path_ids[valid] if valid.any() else path_ids)
 
-            with tr.span("gate"):
-                _, idx = self._gate(gp.moe.router, h2)
-            top1 = self._to_host("sync.top1", idx[:, 0])
+            # the layer's one device->host read: the first choice feeds the
+            # popularity and the path state, all of it the replica mirror
+            idx = self._to_host("sync.top1", idx)
+            top1 = idx[:, 0]
             actual = np.bincount(top1, weights=valid.astype(np.float64),
                                  minlength=cfg.moe.n_experts)
             actual = actual / max(actual.sum(), 1.0)
@@ -518,15 +606,16 @@ class MoEServer:
                 cap = self._valid_capacity(int(valid.sum()), h2.shape[0])
                 min_rep = int(plan.n_replicas.min())
                 se, ro, nr, rw = self._plan_device(plan)
-                y = self._dispatch(gp.moe, h2, se, ro, nr, rw,
-                                   min_replicas=min_rep, cap=cap)
+                y = self._launch(self._dispatch, self._moe_params(li), h2,
+                                 se, ro, nr, rw, min_replicas=min_rep,
+                                 cap=cap)
 
             with tr.span("server.mirror"):
                 # host mirror of the replica split: realized valid-token
                 # count per (device, sub-slot) — what the telemetry
                 # bus/controller observes as post-routing imbalance
                 rep_load = replica_token_counts(
-                    self._to_host("sync.top1", idx), self._host_plan(plan),
+                    idx, self._host_plan(plan),
                     cap, slot_capacity(cap, min_rep), valid=valid,
                     dp_shards=dp_shard_count(self.mesh, h2.shape[0]),
                     route_mode=scfg.route_mode)
@@ -587,13 +676,13 @@ class MoEServer:
         self._plan_device(plan)
         return self._plan_arrays[id(plan)][5]
 
-    def _group_params(self, g):
-        gp = self._gp_cache.get(g)
-        if gp is None:
-            gp = jax.tree.map(lambda a: a[g] if a is not None else None,
-                              self._cparams.stack, is_leaf=lambda a: a is None)
-            self._gp_cache[g] = gp
-        return gp
+    def _moe_params(self, g):
+        """Group ``g``'s MoE params, sliced out of the stack once."""
+        moe_p = self._moe_cache.get(g)
+        if moe_p is None:
+            moe_p = jax.tree.map(lambda a: a[g], self._cparams.stack.moe)
+            self._moe_cache[g] = moe_p
+        return moe_p
 
     # --- serving loop -------------------------------------------------------
     def serve(self, tokens: np.ndarray, lengths=None) -> tuple:
@@ -642,97 +731,49 @@ class MoEServer:
             tokens, lengths, path_init, cache_len=cache_len)
         return PrefillResult(logits, stats, path_ids, cache)
 
-    def _walk_stack(self, x, *, attn, valid, path_ids, has_state, shape):
-        """The group/layer walk shared by full-sequence forward and
-        incremental decode: attention (via ``attn(gp, j, x) ->
-        (x, k_j, v_j)``; k_j None = no cache capture), dense FFN for
-        non-MoE sublayers, and the two-phase MoE core for MoE sublayers.
-        ``shape`` is the (b, s) token grid of ``x``.  Returns
-        (x, stats, path_ids, ks, vs) with ks/vs per-group stacks."""
+    def _walk_stack(self, carry, *, kv, pos, valid, path_ids, has_state):
+        """The layer walk shared by full-sequence forward and incremental
+        decode: per MoE layer one block call, Lina's planner on its one
+        read, and one dispatch call.  ``carry`` is the tokens; ``kv``/``pos``
+        the decode step's cache and positions (None over a sequence).
+        Returns (carry for the head, stats, path_ids, rows), rows the
+        block calls' (k, v) per group."""
         cfg = self.cfg
-        b, s = shape
-        t = b * s
-        d = x.shape[-1]
         stats: List[LayerStats] = []
-        ks: List[jax.Array] = []
-        vs: List[jax.Array] = []
-        n_groups = cfg.n_layers // self.every
+        rows = []
         tr = self.obs.tracer
-        moe_layer_idx = 0
-        for g in range(n_groups):
-            gp = self._group_params(g)
-            ks_g, vs_g = [], []
-            for j in range(self.every):
-                with tr.span("server.attn"):
-                    x, k_j, v_j = attn(gp, j, x)
-                if k_j is not None:
-                    ks_g.append(k_j)
-                    vs_g.append(v_j)
-                h = rms_norm(x, gp.ln2[j], cfg.norm_eps)
-                is_moe = j == self.every - 1
-                if not is_moe:
-                    ffn_p = jax.tree.map(lambda a: a[j] if a is not None else
-                                         None, gp.ffn,
-                                         is_leaf=lambda a: a is None) \
-                        if gp.ffn is not None and gp.ffn.w_in.ndim > 2 else gp.ffn
-                    with tr.span("server.ffn"):
-                        x = x + self._ffn(ffn_p, h)
-                    continue
-                h2 = h.reshape(t, d)
-                y, top1, stat = self._serve_moe(moe_layer_idx, gp, h2, valid,
-                                                path_ids,
-                                                has_state=has_state)
-                moe_y = y.reshape(b, s, d)
-                if gp.shared is not None:
-                    moe_y = moe_y + self._ffn(gp.shared, h)
-                x = x + moe_y
-                stats.append(stat)
-                path_ids = (path_ids * cfg.moe.n_experts + top1) \
-                    % self.profile.n_buckets
-                moe_layer_idx += 1
-            if ks_g:
-                ks.append(jnp.stack(ks_g))
-                vs.append(jnp.stack(vs_g))
-        return x, stats, path_ids, ks, vs
+        for g in range(self.n_groups):
+            with tr.span("server.block", layer=g):
+                x, h2, idx, k, v = self._launch(
+                    self._block, self._cparams, self._gidx[g], carry, kv, pos)
+            rows.append((k, v))
+            y, top1, stat = self._serve_moe(g, h2, idx, valid, path_ids,
+                                            has_state=has_state)
+            carry = (x, h2, y)
+            stats.append(stat)
+            path_ids = (path_ids * cfg.moe.n_experts + top1) \
+                % self.profile.n_buckets
+        return carry, stats, path_ids, rows
 
     def _forward(self, tokens, lengths, path_init, *, cache_len: int):
         """Full-sequence forward; captures an LMCache when cache_len > 0."""
-        cfg = self.cfg
         tokens = np.asarray(tokens)
         b, s = tokens.shape
         if lengths is None:
             lengths = np.full((b,), s, np.int64)
         lengths = np.asarray(lengths, np.int64)
-        x = self._cparams.embed[jnp.asarray(tokens)].astype(
-            jnp.dtype(cfg.dtype))
         valid = (np.arange(s)[None, :] < lengths[:, None]).reshape(b * s)
         path_ids = np.zeros((b * s,), np.int64) if path_init is None \
             else np.asarray(path_init, np.int64).reshape(b * s)
-
-        def attn(gp, j, x):
-            x, k_j, v_j = self._attn(gp, j, x)
-            if not cache_len:
-                return x, None, None
-            pad = cache_len - s
-            if pad:
-                k_j = jnp.pad(k_j, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                v_j = jnp.pad(v_j, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            return x, k_j, v_j
-
-        x, stats, path_ids, ks, vs = self._walk_stack(
-            x, attn=attn, valid=valid, path_ids=path_ids,
-            has_state=False, shape=(b, s))
+        carry, stats, path_ids, rows = self._walk_stack(
+            tokens.astype(np.int32), kv=None, pos=None, valid=valid,
+            path_ids=path_ids, has_state=False)
         with self.obs.tracer.span("server.head"):
-            x = rms_norm(x, self._cparams.final_norm, cfg.norm_eps)
-            last = np.maximum(lengths - 1, 0)
-            x_last = self._to_host("sync.hidden", x)[np.arange(b), last]
-            logits = self._to_host("sync.logits",
-                                   jnp.asarray(x_last) @ self._w_unembed)
-        cache = None
-        if cache_len:
-            kv = KVCache(jnp.stack(ks), jnp.stack(vs))
-            cache = LMCache(kv, None, None, jnp.asarray(lengths, jnp.int32))
-        return (np.asarray(logits), stats, path_ids.reshape(b, s), cache)
+            logits, cache = self._launch(
+                self._head, self._cparams, carry, lengths.astype(np.int32),
+                rows if cache_len else None, None, cache_len=cache_len)
+        logits = self._to_host("sync.logits", logits)
+        return logits, stats, path_ids.reshape(b, s), cache
 
     def decode_batch(self, tokens, cache: LMCache, path_state,
                      valid=None) -> DecodeResult:
@@ -750,35 +791,21 @@ class MoEServer:
         the paper's §5 targets: tiny latency-bound batches.  Per-layer
         top-1 choices keep rolling the path state during generation.
         """
-        cfg = self.cfg
-        tokens = np.asarray(tokens).reshape(-1)
+        tokens = np.asarray(tokens).reshape(-1, 1)
         b = tokens.shape[0]
         if valid is None:
             valid = np.ones((b,), bool)
         valid = np.asarray(valid, bool)
         path_ids = np.asarray(path_state, np.int64).reshape(b).copy()
-        x = self._cparams.embed[jnp.asarray(tokens)][:, None].astype(
-            jnp.dtype(cfg.dtype))                              # [B, 1, d]
-        pos = cache.pos
-        group = [0]   # mutable layer-group cursor for the attn closure
-
-        def attn(gp, j, x):
-            g = group[0]
-            x, k_j, v_j = self._attn_dec(gp, j, x, cache.kv.k[g, j],
-                                         cache.kv.v[g, j], pos)
-            if j == self.every - 1:
-                group[0] += 1
-            return x, k_j, v_j
-
-        x, stats, path_ids, ks, vs = self._walk_stack(
-            x, attn=attn, valid=valid, path_ids=path_ids,
-            has_state=True, shape=(b, 1))
+        carry, stats, path_ids, rows = self._walk_stack(
+            tokens.astype(np.int32), kv=cache.kv, pos=cache.pos,
+            valid=valid, path_ids=path_ids, has_state=True)
         with self.obs.tracer.span("server.head"):
-            x = rms_norm(x, self._cparams.final_norm, cfg.norm_eps)
-            logits = self._to_host("sync.logits", x[:, 0] @ self._w_unembed)
-        new_cache = LMCache(KVCache(jnp.stack(ks), jnp.stack(vs)), None, None,
-                            pos + 1)
-        return DecodeResult(np.asarray(logits), stats, path_ids, new_cache)
+            logits, new_cache = self._launch(
+                self._head, self._cparams, carry, cache.pos, rows, cache.kv,
+                cache_len=0)
+        logits = self._to_host("sync.logits", logits)
+        return DecodeResult(logits, stats, path_ids, new_cache)
 
 
 def profile_from_training(cfg: ModelConfig, params, batches,

@@ -8,6 +8,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import REGISTRY, get_config
 from repro.configs.base import MoEConfig
@@ -106,8 +107,9 @@ def test_serve_layer_replicated_buffers_match_unreplicated():
 
 # --- server: plan cache wired into the serve loop ---------------------------
 
-def _smoke_server(policy="lina", plan_cache=True, capacity_factor=None):
-    cfg = get_config("gpt2-moe").smoke()
+def _smoke_server(policy="lina", plan_cache=True, capacity_factor=None,
+                  arch="gpt2-moe"):
+    cfg = get_config(arch).smoke()
     if capacity_factor is not None:
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe,
@@ -289,6 +291,125 @@ def test_decode_batch_padding_rows_are_inert():
         valid=np.array([True, True, False, False]))
     np.testing.assert_allclose(dec4.logits[:2], dec.logits, atol=1e-4,
                                rtol=1e-4)
+
+
+# --- the serving walk: block / dispatch / head calls ------------------------
+
+@pytest.mark.parametrize("arch", ["gpt2-moe", "llama4-maverick-400b-a17b"])
+def test_walk_matches_lm_prefill_and_decode_step(arch):
+    """The walk's block and head calls compute what the one-program model
+    computes: prefill logits against ``lm.forward_prefill`` and a decode
+    step's logits and cache against ``lm.decode_step``, both under the
+    same uniform plan.  llama4's smoke stack has ``every = 2`` (a dense
+    sublayer inside each block call) and a shared expert (added with the
+    residual in the next block or the head)."""
+    cfg, server = _smoke_server(capacity_factor=16.0, arch=arch)
+    if arch != "gpt2-moe":
+        assert server.every == 2 and server._cparams.stack.shared is not None
+    toks = np.random.RandomState(14).randint(0, cfg.vocab_size, (2, 9))
+    plan = PlanArrays.from_plan(identity_plan(
+        cfg.moe.n_experts, server.n_dev, server.scfg.max_pack))
+    pre = server.prefill_batch(toks[:, :8], cache_len=12)
+    ref = lm_mod.forward_prefill(None, cfg, server.params,
+                                 {"tokens": jnp.asarray(toks[:, :8])},
+                                 serve_plan=plan, serve_top_k=1)
+    np.testing.assert_allclose(pre.logits, np.asarray(ref.logits),
+                               atol=1e-5, rtol=1e-5)
+    dec = server.decode_batch(toks[:, 8], pre.cache, pre.path_ids[:, -1])
+    ref_logits, ref_cache, ref_top1 = lm_mod.decode_step(
+        None, cfg, server.params, pre.cache, jnp.asarray(toks[:, 8]),
+        serve_plan=plan, serve_top_k=1)
+    np.testing.assert_allclose(dec.logits, np.asarray(ref_logits),
+                               atol=1e-5, rtol=1e-5)
+    for got, want in ((dec.cache.kv.k, ref_cache.kv.k),
+                      (dec.cache.kv.v, ref_cache.kv.v),
+                      (dec.cache.pos, ref_cache.pos)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-6)
+    np.testing.assert_array_equal(
+        [s.actual_pop for s in dec.stats],
+        [np.bincount(t, minlength=cfg.moe.n_experts) / 2
+         for t in np.asarray(ref_top1)])
+
+
+def test_returned_caches_stay_valid_after_later_decodes():
+    """A cache that ``prefill_batch`` or ``decode_batch`` returned is never
+    donated or written: the engine decodes again from a kept batch and cuts
+    slot rows out of it after later steps (``DecodeSlot.batch_ref``), as
+    the serving benchmark's warm-up does."""
+    from repro.runtime.engine import DecodeSlot
+    cfg, server = _smoke_server(capacity_factor=16.0)
+    toks = np.random.RandomState(15).randint(0, cfg.vocab_size, (2, 8))
+    pre = server.prefill_batch(toks, cache_len=12)
+    path = pre.path_ids[:, -1]
+    host = lambda c: [np.array(a) for a in (c.kv.k, c.kv.v, c.pos)]
+    pre_host = host(pre.cache)
+    d1 = server.decode_batch(toks[:, -1], pre.cache, path)
+    d1_again = server.decode_batch(toks[:, -1], pre.cache, path)
+    np.testing.assert_array_equal(d1.logits, d1_again.logits)
+    d1_host = host(d1.cache)
+    d2 = server.decode_batch(toks[:, 0], d1.cache, d1.path_state)
+    server.decode_batch(toks[:, 1], d2.cache, d2.path_state)
+    for cache, want in ((pre.cache, pre_host), (d1.cache, d1_host)):
+        for got, ref in zip(host(cache), want):
+            np.testing.assert_array_equal(got, ref)
+    slot = DecodeSlot(rid=0, arrival=0.0, prompt_len=8, max_new_tokens=4,
+                      cap=12, kv_k=None, kv_v=None, pos=9, path_scalar=0,
+                      path_history=[0], gen_tokens=[0], ttft=0.0,
+                      batch_ref=d1.cache, batch_row=1)
+    k, v = slot.materialize()
+    np.testing.assert_array_equal(np.asarray(k), d1_host[0][:, :, 1])
+    np.testing.assert_array_equal(np.asarray(v), d1_host[1][:, :, 1])
+    for a in (*pre.cache.kv, *d1.cache.kv, *d2.cache.kv):
+        assert not a.is_deleted()
+
+
+def test_decode_step_launches_two_programs_a_moe_layer_and_runs_no_op():
+    """After warm-up, a decode forward launches 2 * n_moe + 1 compiled
+    programs (a block and a dispatch a MoE layer, then the head) and
+    nothing else, and makes n_moe + 1 device->host reads.  With jit's C++
+    fast path off, every jitted call (a ``jnp`` op's too) goes through
+    ``_run_python_pjit`` and every primitive run op by op through
+    ``EvalTrace.process_primitive``: the hooks see each launch."""
+    from jax._src import core as jax_core
+    from jax._src import pjit as jax_pjit
+    cfg, server = _smoke_server(capacity_factor=16.0)
+    toks = np.random.RandomState(16).randint(0, cfg.vocab_size, (2, 8))
+    pre = server.prefill_batch(toks, cache_len=12)
+    path = pre.path_ids[:, -1]
+    met = server.obs.metrics
+    launched = []
+    process = jax_core.EvalTrace.process_primitive
+    run_python = jax_pjit._run_python_pjit
+
+    def op_by_op(trace, prim, args, params):
+        launched.append(prim.name)
+        return process(trace, prim, args, params)
+
+    def jitted(p, args_flat, fun, *args, **kwargs):
+        launched.append(fun.__name__)
+        return run_python(p, args_flat, fun, *args, **kwargs)
+
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax_pjit, "_get_fastpath_data", lambda *a, **k: None)
+            mp.setattr(jax_pjit, "_run_python_pjit", jitted)
+            mp.setattr(jax_core.EvalTrace, "process_primitive", op_by_op)
+            jax.clear_caches()
+            server.decode_batch(toks[:, -1], pre.cache, path)   # warm-up
+            launched.clear()
+            jnp.ones((2,)) + 1                 # the hooks see eager work
+            assert {"broadcast_in_dim", "add"} <= set(launched)
+            launched.clear()
+            calls0 = met.value("server_program_calls_total")
+            syncs0 = met.value("server_host_syncs_total")
+            server.decode_batch(toks[:, -1], pre.cache, path)
+    finally:
+        jax.clear_caches()                     # fast paths back on
+    n_moe = cfg.n_moe_layers
+    assert launched == ["_block_fn", "_dispatch_fn"] * n_moe + ["_head_fn"]
+    assert met.value("server_program_calls_total") - calls0 == 2 * n_moe + 1
+    assert met.value("server_host_syncs_total") - syncs0 == n_moe + 1
 
 
 # --- stacked per-layer plans through decode_step -----------------------------
