@@ -44,6 +44,7 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(ROOT))
     import jax
 
+    from bench import blocks
     from bench.drivers.common import RunSpec
     from bench.flops import peaks
     from bench.run import load_json
@@ -54,6 +55,7 @@ def main(argv=None) -> int:
     cfg = load_json(BENCH / "configs" / f"{cell['config']}.json")
     mix = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
     limits = load_json(BENCH / "limits" / f"{cell['name']}.json")
+    block = blocks.named(cfg)
     devices = jax.devices()[:cell["chips"]]
     if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
         print("control.py: the cell's chips are required", file=sys.stderr)
@@ -66,8 +68,9 @@ def main(argv=None) -> int:
         out = {"seed": seed}
         if mix["driver"] == "serve":
             from bench.drivers import serve
-            spec = RunSpec(cell=cell, cfg=cfg, mix=mix, limits=limits,
-                           seed=seed, seconds=args.seconds, trace=False,
+            spec = RunSpec(cell=cell, cfg=cfg, block=block, mix=mix,
+                           limits=limits, seed=seed, seconds=args.seconds,
+                           trace=False,
                            devices=devices, peak=peaks(devices[0].device_kind),
                            process_age=lambda: 0.0)
             res = serve.run(spec, control=True)
@@ -77,15 +80,15 @@ def main(argv=None) -> int:
                                              for c in res.checks)
         else:
             from bench.drivers.train import compare, reference_readings
-            ref = reference_readings(cfg, mix, seed, devices)
+            ref = reference_readings(block, cfg, mix, seed, devices)
             runs = {"control_fp8": {"precision": "fp8"},
                     "fault_half_batch": {"fault": "half_batch"},
                     "fault_no_exchange": {"fault": "no_exchange"}}
             for tag in args.runs.split(","):
                 if tag == "fault_no_exchange" and len(devices) == 1:
                     continue
-                got = compare(reference_readings(cfg, mix, seed, devices,
-                                                 **runs[tag]), ref)
+                got = compare(reference_readings(block, cfg, mix, seed,
+                                                 devices, **runs[tag]), ref)
                 out.update({f"{tag}.{n}": v for n, v in got.items()})
                 out[f"{tag}.correct"] = judged(got)
         line = json.dumps(out)

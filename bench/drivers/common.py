@@ -13,11 +13,6 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
-# the configuration file's keys that are fields of the program's config
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
-              "vocab_size", "ffn_type", "dtype", "param_dtype")
-MOE_KEYS = ("n_experts", "top_k", "capacity_factor", "aux_loss_weight")
-
 # JAX's monitoring event for building one executable in this process (a
 # backend compile or a fetch from the persistent cache)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -27,6 +22,7 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 class RunSpec:
     cell: dict
     cfg: dict
+    block: Any                      # bench/blocks/<cfg["block"]>.py
     mix: dict
     limits: dict
     seed: int
@@ -64,15 +60,16 @@ class Result:
     trace: Any = None
 
 
-def program_config(cfg: dict):
+def program_config(cfg: dict, block):
     """The program's ModelConfig for a configuration file: its named
-    configuration with every size the file states."""
+    configuration with every field the file states (the block's
+    ``MODEL_KEYS`` and ``MOE_KEYS``)."""
     from repro.configs import get_config
     base = get_config(cfg["program_config"])
-    mc = dataclasses.replace(base, **{k: cfg[k] for k in MODEL_KEYS})
-    moe = dataclasses.replace(mc.moe, **{k: cfg[k] for k in MOE_KEYS})
+    mc = dataclasses.replace(base, **{k: cfg[k] for k in block.MODEL_KEYS})
+    moe = dataclasses.replace(mc.moe, **{k: cfg[k] for k in block.MOE_KEYS})
     mc = dataclasses.replace(mc, moe=moe)
-    for k in MODEL_KEYS:
+    for k in block.MODEL_KEYS:
         if getattr(mc, k) != cfg[k]:
             raise ValueError(f"{k}: the program runs {getattr(mc, k)!r}, "
                              f"the configuration states {cfg[k]!r}")

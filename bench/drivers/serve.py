@@ -63,10 +63,11 @@ def build(spec):
                           cfg["capacity_factor"]) < rows:
         raise ValueError("a decode step could drop tokens; the reference's "
                          "capacity rule would not hold")
-    mc = program_config(cfg)
+    mc = program_config(cfg, spec.block)
     struct = jax.eval_shape(partial(lm_mod.init_params, mc),
                             jax.random.PRNGKey(0))
-    params = weights.make_program(cfg, seed, jnp.bfloat16, struct)
+    params = weights.make_program(spec.block, cfg, seed, jnp.bfloat16,
+                                  struct)
     prof = profile_from_training(
         mc, params, traffic_gen.profile_batches(
             mix, cfg["vocab_size"], seed, **mix["profile"]),
@@ -250,7 +251,7 @@ def serve_window(engine, spec, sched, preroll_s: float) -> SimpleNamespace:
         trace=win.trace)
 
 
-def measure(sv, sched, cfg):
+def measure(sv, sched, cfg, block):
     """End-to-end metrics of a window, the finished requests by schedule
     index, the model FLOPs processed in the window, and the time to first
     token of every request due in it."""
@@ -268,8 +269,9 @@ def measure(sv, sched, cfg):
         for n, t in enumerate(times):
             if t0 <= t <= t1:
                 n_tok += 1
-                work_flops += (flops.serve_prefill_flops(cfg, plen) if n == 0
-                               else flops.serve_decode_flops(cfg, plen + n - 1))
+                work_flops += (
+                    flops.serve_prefill_flops(block, cfg, plen) if n == 0
+                    else flops.serve_decode_flops(block, cfg, plen + n - 1))
                 if n:
                     gaps.append(times[n] - times[n - 1])
     for j in win_due:
@@ -296,7 +298,7 @@ def run(spec, control: bool = False) -> Result:
     that the control puts first."""
     import jax.numpy as jnp
 
-    cfg, mix, seed = spec.cfg, spec.mix, spec.seed
+    cfg, mix, seed, block = spec.cfg, spec.mix, spec.seed, spec.block
     engine = build(spec)
     sched = traffic_gen.serve_schedule(
         mix, cfg["vocab_size"], seed,
@@ -304,16 +306,17 @@ def run(spec, control: bool = False) -> Result:
     sv = serve_window(engine, spec, sched, mix["preroll_s"])
     del engine
     gc.collect()
-    e2e, by_req, win_due, failed, work_flops, ttft = measure(sv, sched, cfg)
+    e2e, by_req, win_due, failed, work_flops, ttft = measure(sv, sched, cfg,
+                                                             block)
     window_s = sv.t1 - sv.t0
 
     # --- correctness: every finished request against the reference -------
     finished = [(sched[j].tokens, np.asarray(r.tokens, np.int32))
                 for j, r in sorted(by_req.items())]
-    w = weights.make(cfg, seed, jnp.bfloat16)
+    w = weights.make(block, cfg, seed, jnp.bfloat16)
 
     def gap_readings(who, ctl):
-        gaps = np.concatenate(reference.serve_gaps(w, finished, cfg,
+        gaps = np.concatenate(reference.serve_gaps(block, w, finished, cfg,
                                                    control=ctl))
         out = {"max_logit_gap": float(gaps.max()),
                "mean_logit_gap": float(gaps.mean()),

@@ -26,19 +26,19 @@ from bench.drivers.common import (Record, Result, Window, annotate,
 CHECK_STEPS = 3
 
 
-def first_gradient(opt_state, beta1: float, cfg: dict) -> dict:
+def first_gradient(block, opt_state, beta1: float, cfg: dict) -> dict:
     """The first (clipped) gradient as AdamW got it, from its first moment
     after one step (m / (1 - beta1)), as float32 host arrays of the
     benchmark's flat shapes."""
-    flat = weights.shapes(cfg)
+    flat = weights.shapes(block, cfg)
     return {n: np.asarray(x, np.float32).reshape(flat[n][0]) / (1.0 - beta1)
-            for n, x in weights.named_leaves(opt_state.m).items()}
+            for n, x in weights.named_leaves(block, opt_state.m).items()}
 
 
-def change_norms(a, b) -> dict:
+def change_norms(block, a, b) -> dict:
     import jax
     import jax.numpy as jnp
-    na, nb = weights.named_leaves(a), weights.named_leaves(b)
+    na, nb = weights.named_leaves(block, a), weights.named_leaves(block, b)
     norms = jax.jit(lambda x, y: {n: jnp.sqrt(jnp.sum(jnp.square(
         x[n].astype(jnp.float32) - y[n].astype(jnp.float32)))) for n in x})(
         na, nb)
@@ -88,22 +88,23 @@ def head_diff(prog, ref) -> float:
                            / ref_n[live]))
 
 
-def reference_readings(cfg: dict, mix: dict, seed: int, devices, **kw):
+def reference_readings(block, cfg: dict, mix: dict, seed: int, devices,
+                       **kw):
     """The reference's readings of the checked steps on the cell's chips
-    (its experts split over them); ``kw`` selects the control or a fault
-    (``reference.train_readings``)."""
+    (the block's expert weights split over them); ``kw`` selects the
+    control or a fault (``reference.train_readings``)."""
     import jax.numpy as jnp
     shardings = None
     if len(devices) > 1:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         rmesh = Mesh(np.array(devices), ("x",))
         shardings = {n: NamedSharding(rmesh, P(None, "x") if n in
-                                      ("wi", "wo_e") else P())
-                     for n in weights.shapes(cfg)}
+                                      block.EXPERT_SHARDED else P())
+                     for n in weights.shapes(block, cfg)}
     stream = traffic_gen.LMStream(mix, cfg["vocab_size"], seed)
     oc = mix["optimizer"]
     return reference.train_readings(
-        weights.make(cfg, seed, jnp.float32, shardings),
+        block, weights.make(block, cfg, seed, jnp.float32, shardings),
         [stream.batch(s) for s in range(CHECK_STEPS)], cfg,
         dict(oc, betas=tuple(oc["betas"])), ep=len(devices),
         shardings=shardings, **kw)
@@ -120,9 +121,9 @@ def run(spec) -> Result:
     from repro.optim.adamw import AdamWConfig, init_opt_state
     from repro.runtime import Trainer, TrainerConfig
 
-    cfg, mix, seed = spec.cfg, spec.mix, spec.seed
+    cfg, mix, seed, block = spec.cfg, spec.mix, spec.seed, spec.block
     chips = len(spec.devices)
-    mc = program_config(cfg)
+    mc = program_config(cfg, block)
     oc = mix["optimizer"]
     opt = AdamWConfig(lr=oc["lr"], betas=tuple(oc["betas"]), eps=oc["eps"],
                       weight_decay=oc["weight_decay"],
@@ -139,8 +140,8 @@ def run(spec) -> Result:
                       mesh=mesh)
     struct = jax.eval_shape(partial(lm_mod.init_params, mc),
                             jax.random.PRNGKey(0))
-    make_params = partial(weights.make_program, cfg, seed, jnp.float32,
-                          struct, trainer._param_sh)
+    make_params = partial(weights.make_program, block, cfg, seed,
+                          jnp.float32, struct, trainer._param_sh)
     params = make_params()
     opt_state = jax.device_put(init_opt_state(params, opt), trainer._opt_sh)
     stream = traffic_gen.LMStream(mix, cfg["vocab_size"], seed)
@@ -153,9 +154,10 @@ def run(spec) -> Result:
         params, opt_state, m = trainer.step_fn(params, opt_state, feed(step))
         prog["loss"].append(float(m["loss"]))
         if step == 0:
-            prog["grad0"] = first_gradient(opt_state, opt.betas[0], cfg)
+            prog["grad0"] = first_gradient(block, opt_state, opt.betas[0],
+                                           cfg)
     p0 = make_params()
-    prog["change"] = change_norms(params, p0)
+    prog["change"] = change_norms(block, params, p0)
     del p0
 
     tracing = spec.trace
@@ -185,7 +187,7 @@ def run(spec) -> Result:
     del params, opt_state, m, trainer
     gc.collect()
 
-    ref = reference_readings(cfg, mix, seed, spec.devices)
+    ref = reference_readings(block, cfg, mix, seed, spec.devices)
     readings = compare(prog, ref)
     for name, v in readings.items():
         print(f"reading {name} = {v!r}", file=sys.stderr)
@@ -201,9 +203,9 @@ def run(spec) -> Result:
                  window_s=window_s, n_chips=chips,
                  counters={"compiles_in_window": comp["n"], "steps": n_steps},
                  work={"model_flops": tokens * flops.train_flops_per_token(
-                     cfg, mix["seq"]),
+                     block, cfg, mix["seq"]),
                      "kept_rows": rows,
-                     "experts_touched": n_steps * cfg["n_layers"]
+                     "experts_touched": n_steps * block.moe_layers(cfg)
                      * cfg["n_experts"]},
                  trace=win.trace)
     return Result(e2e=e2e, record=rec, attempted=n_steps, failed=bad,

@@ -11,6 +11,8 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
+from bench import blocks
+
 PEAKS_FILE = Path(__file__).resolve().parent / "peaks.json"
 
 
@@ -37,45 +39,36 @@ def share_pct(least_s: float, took_s: float):
     return 100.0 * least_s / took_s
 
 
-# --- matrix parameters touched per token ---------------------------------
+# --- model FLOPs, from the block's counts ----------------------------------
 
-def layer_params(cfg: dict, k: int) -> dict:
-    """Multiply-accumulate weights one token meets in one layer, with k
-    experts: attention projections, router, the k experts' FFN."""
-    d, f, e = cfg["d_model"], cfg["d_ff"], cfg["n_experts"]
-    hd = d // cfg["n_heads"]
-    attn = d * cfg["n_heads"] * hd * 2 + d * cfg["n_kv_heads"] * hd * 2
-    n_mats = 3 if cfg["ffn_type"] == "swiglu" else 2
-    return {"attn": attn, "router": d * e, "experts": k * n_mats * d * f}
+def _head(cfg: dict) -> int:
+    return cfg["d_model"] * cfg["vocab_size"]
 
 
-def train_flops_per_token(cfg: dict, seq: int) -> float:
+def train_flops_per_token(block, cfg: dict, seq: int) -> float:
     """Forward and backward model FLOPs per token: 6 x the weights a token
-    meets (top-k experts, LM head; the embedding is a lookup), plus causal
-    attention's score and value products (6 x layers x seq x d, half of the
-    full-square count).  Recomputation is not counted."""
-    per_layer = sum(layer_params(cfg, cfg["top_k"]).values())
-    n = cfg["n_layers"] * per_layer + cfg["d_model"] * cfg["vocab_size"]
-    return 6.0 * n + 6.0 * cfg["n_layers"] * seq * cfg["d_model"]
+    meets (in every layer with top-k experts, and the LM head; the
+    embedding is a lookup), plus the block's attention FLOPs of a training
+    token.  Recomputation is not counted."""
+    n = sum(block.layer_params(cfg, cfg["top_k"])) + _head(cfg)
+    return 6.0 * n + block.attention_flops_train(cfg, seq)
 
 
-def serve_prefill_flops(cfg: dict, s: int) -> float:
+def serve_prefill_flops(block, cfg: dict, s: int) -> float:
     """Forward FLOPs of one prompt of length ``s``: every position through
-    every layer with the serving top-k, causal attention, and the LM head
-    for the last position only."""
-    per_layer = sum(layer_params(cfg, cfg["serve_top_k"]).values())
-    return (2.0 * cfg["n_layers"] * per_layer * s
-            + 2.0 * cfg["n_layers"] * s * s * cfg["d_model"]
-            + 2.0 * cfg["d_model"] * cfg["vocab_size"])
+    every layer with the serving top-k, the block's attention, and the LM
+    head for the last position only."""
+    n = sum(block.layer_params(cfg, cfg["serve_top_k"]))
+    return (2.0 * n * s + block.attention_flops_prefill(cfg, s)
+            + 2.0 * _head(cfg))
 
 
-def serve_decode_flops(cfg: dict, pos: int) -> float:
+def serve_decode_flops(block, cfg: dict, pos: int) -> float:
     """Forward FLOPs of one decoded token at absolute position ``pos``
     (attending over pos + 1 cached positions), LM head included."""
-    per_layer = sum(layer_params(cfg, cfg["serve_top_k"]).values())
-    return (2.0 * cfg["n_layers"] * per_layer
-            + 4.0 * cfg["n_layers"] * (pos + 1) * cfg["d_model"]
-            + 2.0 * cfg["d_model"] * cfg["vocab_size"])
+    n = sum(block.layer_params(cfg, cfg["serve_top_k"]))
+    return (2.0 * n + block.attention_flops_decode(cfg, pos)
+            + 2.0 * _head(cfg))
 
 
 # --- kernels ---------------------------------------------------------------
@@ -83,16 +76,16 @@ def serve_decode_flops(cfg: dict, pos: int) -> float:
 def expert_ffn_work(rows: float, experts: float, cfg: dict, *,
                     passes: int = 1, bytes_per_el: int = 2):
     """(FLOPs, bytes) of the grouped expert FFN over ``rows`` kept
-    (token, expert) rows touching ``experts`` distinct experts.
+    (token, expert) rows touching ``experts`` distinct experts of the
+    block the configuration names.
 
     ``passes`` is 1 for a forward and 3 for forward and backward (the
     backward is a data gradient and a weight gradient per matrix).  Bytes
     are each touched expert's weights read once per pass plus the rows in
     and out; for a backward the weight gradients are written as well."""
-    d, f = cfg["d_model"], cfg["d_ff"]
-    n_mats = 3 if cfg["ffn_type"] == "swiglu" else 2
-    flops = 2.0 * rows * d * f * n_mats * passes
-    weights = experts * n_mats * d * f * bytes_per_el
+    d, p = cfg["d_model"], blocks.named(cfg).expert_params(cfg)
+    flops = 2.0 * rows * p * passes
+    weights = experts * p * bytes_per_el
     nbytes = weights * passes + 2.0 * rows * d * bytes_per_el * passes
     if passes > 1:
         nbytes += weights            # weight gradients written

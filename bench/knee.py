@@ -37,7 +37,7 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from bench import traffic_gen
+    from bench import blocks, traffic_gen
     from bench.drivers import serve
     from bench.drivers.common import RunSpec
     from bench.flops import peaks
@@ -52,8 +52,9 @@ def main(argv=None) -> int:
     if devices[0].platform != "tpu":
         print("knee.py: a TPU is required", file=sys.stderr)
         return 3
-    spec = RunSpec(cell=cell, cfg=cfg, mix=mix, limits={}, seed=args.seed,
-                   seconds=args.seconds, trace=False, devices=devices,
+    spec = RunSpec(cell=cell, cfg=cfg, block=blocks.named(cfg), mix=mix,
+                   limits={}, seed=args.seed, seconds=args.seconds,
+                   trace=False, devices=devices,
                    peak=peaks(devices[0].device_kind), process_age=lambda: 0.0)
     engine = serve.build(spec)
     for k, rate in enumerate(float(r) for r in args.rates.split(",")):
@@ -62,7 +63,8 @@ def main(argv=None) -> int:
             m, cfg["vocab_size"], args.seed + k,
             (m["preroll_s"], args.seconds, args.seconds))
         sv = serve.serve_window(engine, spec, sched, m["preroll_s"])
-        e2e, by_req, win_due, failed, _, _ = serve.measure(sv, sched, cfg)
+        e2e, by_req, win_due, failed, _, _ = serve.measure(sv, sched, cfg,
+                                                           spec.block)
         q = args.seconds / 4
         first = [b for t, b in sv.backlog if t < q]
         last = [b for t, b in sv.backlog if t >= 3 * q]

@@ -1,4 +1,5 @@
-"""Plain float32 reference of the MoE decoder the program runs.
+"""Plain float32 reference of the MoE decoders the program runs: what every
+block shares.
 
 Straightforward ``jax.numpy`` at ``Precision.HIGHEST``, no kernels, no
 cache, no batching across requests.  It imports nothing of the program and
@@ -6,13 +7,11 @@ takes nothing the program made: the weights come from ``bench.weights``
 (regenerated from the seed), the tokens from the traffic generator or from
 the program's served output.
 
-The block is the repo's transformer block, which departs from the
-published GPT-2 / Transformer-XL blocks (the configuration files say so):
-RMSNorm (eps 1e-5) before attention and before the MoE layer, RoPE
-(theta 1e4) on queries and keys, no biases, causal attention; the MoE
-layer is a softmax router, top-k experts with the top-k weights
-renormalised, tanh-GELU expert FFNs, and the output summed into the
-residual.
+A block module (``bench/blocks/<block>.py``, named by the configuration)
+holds the layer equations: attention, router, experts and how the layers
+are walked.  Here is the frame they sit in (token embedding, final RMSNorm,
+untied LM head, the loss and the logits), the MoE dispatch every block's
+router hands its choices to, the fp8 control, AdamW and the readings.
 
 Capacity rule (shared with the program; the reference must drop what the
 program drops): per expert, ``capacity(n)`` = max(8, 8 * ceil((floor(n * k
@@ -31,6 +30,7 @@ rounded operands, and the cotangents stay in float32.
 """
 from __future__ import annotations
 
+import json
 import math
 from functools import partial
 
@@ -74,47 +74,14 @@ def rms_norm(x, w):
         * w.astype(F32)
 
 
-def rope(x, pos):
+def rope(x, pos, theta: float = ROPE_THETA):
     """x [B, S, H, hd]; pos [B, S]."""
     hd = x.shape[-1]
-    freqs = ROPE_THETA ** (-jnp.arange(0, hd, 2, dtype=F32) / hd)
+    freqs = theta ** (-jnp.arange(0, hd, 2, dtype=F32) / hd)
     ang = pos[..., None].astype(F32) * freqs
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
-def attention(x, lw, cfg, precision):
-    """Causal self-attention sub-block with its residual; x [B, S, d]."""
-    b, s, d = x.shape
-    hn, kvn = cfg["n_heads"], cfg["n_kv_heads"]
-    hd = d // hn
-    h = rms_norm(x, lw["ln1"])
-    q = mm("bsd,de->bse", h, lw["wq"], precision).reshape(b, s, hn, hd)
-    k = mm("bsd,de->bse", h, lw["wk"], precision).reshape(b, s, kvn, hd)
-    v = mm("bsd,de->bse", h, lw["wv"], precision).reshape(b, s, kvn, hd)
-    pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    q, k = rope(q, pos), rope(k, pos)
-    if kvn != hn:
-        k = jnp.repeat(k, hn // kvn, axis=2)
-        v = jnp.repeat(v, hn // kvn, axis=2)
-    logits = mm("bqhd,bkhd->bhqk", q, k, precision) / math.sqrt(hd)
-    causal = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    p = jax.nn.softmax(jnp.where(causal, logits, -1e30), axis=-1)
-    o = mm("bhqk,bkhd->bqhd", p, v, precision).reshape(b, s, hn * hd)
-    return x + mm("bse,ed->bsd", o, lw["wo"], precision)
-
-
-def route(h, router, k, precision):
-    """h [..., T, d] -> (probs [..., T, E], idx [..., T, k], w [..., T, k])."""
-    probs = jax.nn.softmax(mm("...td,de->...te", h, router, precision), -1)
-    w, idx = jax.lax.top_k(probs, k)
-    return probs, idx, w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
 
 
 def ranks(idx, n_experts, member=None):
@@ -131,22 +98,10 @@ def ranks(idx, n_experts, member=None):
     return jnp.sum(pos * oh, -1)                               # [G,T,k]
 
 
-def experts(h, wdense, lw, precision):
-    """sum_e wdense[:, e] * FFN_e(h) over every expert; h [T, d]."""
-    def one(y, xs):
-        wi, wo, we = xs
-        a = gelu(mm("td,df->tf", h, wi, precision))
-        return y + we[:, None] * mm("tf,fd->td", a, wo, precision), None
-
-    y0 = jnp.zeros(h.shape, F32)
-    y, _ = jax.lax.scan(jax.checkpoint(one), y0,
-                        (lw["wi"], lw["wo_e"], wdense.T))
-    return y
-
-
-def _layers(w):
-    names = ("ln1", "ln2", "wq", "wk", "wv", "wo", "router", "wi", "wo_e")
-    return {n: w[n] for n in names}
+def combine_weights(idx, w, keep, n_experts: int):
+    """Dense combine weights [..., T, E] of the kept choices."""
+    return jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=F32)
+                   * (w * keep)[..., None], axis=-2)
 
 
 # --- training ----------------------------------------------------------------
@@ -162,7 +117,42 @@ def _no_exchange(wd, ep: int):
     return (own[:, None, :, None] * local[:, :, None, :]).reshape(g, t, e)
 
 
-def train_loss(w, batch, cfg, ep: int, precision: str = "f32",
+class TrainDispatch:
+    """A training MoE layer's dispatch, the same for every block: the
+    token groups are ``ep`` sequence shards (batch-major, the program's
+    expert-parallel layout) of ``capacity`` rows per expert; the fault
+    ``"no_exchange"`` leaves out the exchange between chips.
+
+    ``moe(h, route, experts)``: ``route(groups [G, T, d])`` is the block's
+    router, giving (probs, choices [G, T, k], their weights);
+    ``experts(rows [G*T, d], combine [G*T, E])`` the block's weighted sum
+    over its experts.  Returns the output in h's layout [B, S, d] and the
+    probs, choices and kept choices per group."""
+
+    def __init__(self, b: int, s: int, ep: int, cfg: dict,
+                 fault: str | None = None):
+        self.b, self.s, self.ep, self.fault = b, s, ep, fault
+        self.n_experts, self.k = cfg["n_experts"], cfg["top_k"]
+        self.cap = capacity(b * s // ep, self.n_experts, self.k,
+                            cfg["capacity_factor"])
+
+    def __call__(self, h, route, experts):
+        b, s, ep, e = self.b, self.s, self.ep, self.n_experts
+        t_loc = b * s // ep
+        hs = h.reshape(b, ep, s // ep, -1).transpose(1, 0, 2, 3) \
+            .reshape(ep, t_loc, -1)
+        probs, idx, gw = route(hs)
+        keep = ranks(idx, e) < self.cap
+        wd = combine_weights(idx, gw, keep, e)                 # [ep,T,E]
+        if self.fault == "no_exchange":
+            wd = _no_exchange(wd, ep)
+        y = experts(hs.reshape(ep * t_loc, -1), wd.reshape(ep * t_loc, e))
+        y = y.reshape(ep, b, s // ep, -1).transpose(1, 0, 2, 3) \
+            .reshape(b, s, -1)
+        return y, probs, idx, keep
+
+
+def train_loss(block, w, batch, cfg, ep: int, precision: str = "f32",
                fault: str | None = None):
     """(Mean next-token cross entropy plus the routers' auxiliary loss,
     share of expert choices kept), with the expert layer's token groups
@@ -177,32 +167,9 @@ def train_loss(w, batch, cfg, ep: int, precision: str = "f32",
         tokens, labels = tokens[:tokens.shape[0] // 2], \
             labels[:labels.shape[0] // 2]
     b, s = tokens.shape
-    e, k, cf = cfg["n_experts"], cfg["top_k"], cfg["capacity_factor"]
-    t_loc = b * s // ep
-    cap = capacity(t_loc, e, k, cf)
+    moe = TrainDispatch(b, s, ep, cfg, fault)
     x = w["embed"].astype(F32)[tokens]
-
-    def layer(x, lw):
-        x = attention(x, lw, cfg, precision)
-        h = rms_norm(x, lw["ln2"])
-        hs = h.reshape(b, ep, s // ep, -1).transpose(1, 0, 2, 3) \
-            .reshape(ep, t_loc, -1)
-        probs, idx, gw = route(hs, lw["router"], k, precision)
-        keep = ranks(idx, e) < cap
-        wd = jnp.sum(jax.nn.one_hot(idx, e, dtype=F32)
-                     * (gw * keep)[..., None], axis=2)          # [ep,T,E]
-        if fault == "no_exchange":
-            wd = _no_exchange(wd, ep)
-        f = jnp.mean(jax.nn.one_hot(idx[..., 0], e, dtype=F32), axis=1)
-        aux = cfg["aux_loss_weight"] * e * jnp.mean(
-            jnp.sum(f * jnp.mean(probs, axis=1), -1))
-        y = experts(hs.reshape(ep * t_loc, -1), wd.reshape(ep * t_loc, e),
-                    lw, precision)
-        y = y.reshape(ep, b, s // ep, -1).transpose(1, 0, 2, 3) \
-            .reshape(b, s, -1)
-        return x + y, (aux, jnp.mean(keep.astype(F32)))
-
-    x, (auxs, kept) = jax.lax.scan(jax.checkpoint(layer), x, _layers(w))
+    x, auxs, kept = block.train_layers(x, w, cfg, moe, precision)
     x = rms_norm(x, w["final_norm"])
 
     def ce(tot, xs):
@@ -246,12 +213,13 @@ def adamw_step(w, m, v, step, grads, ocfg):
     return w, m, v, g
 
 
-def train_readings(w0, batches, cfg, ocfg, ep: int, precision: str = "f32",
-                   shardings=None, fault: str | None = None):
+def train_readings(block, w0, batches, cfg, ocfg, ep: int,
+                   precision: str = "f32", shardings=None,
+                   fault: str | None = None):
     """Three training steps from ``w0`` on ``batches``: the loss of each,
     the first (clipped) gradient as float32 host arrays, and the per-leaf
     norm of the weights' change after the three."""
-    loss_grad = jax.value_and_grad(partial(train_loss, cfg=cfg, ep=ep,
+    loss_grad = jax.value_and_grad(partial(train_loss, block, cfg=cfg, ep=ep,
                                            precision=precision, fault=fault),
                                    has_aux=True)
 
@@ -282,70 +250,79 @@ def train_readings(w0, batches, cfg, ocfg, ep: int, precision: str = "f32",
 
 # --- serving -----------------------------------------------------------------
 
-def serve_logits(w, tokens, prompt_len, caps, query, cfg, precision="f32"):
-    """Next-token logits of a block of requests.
+class ServeDispatch:
+    """A serving MoE layer's dispatch, the same for every block: each
+    request's prompt positions form one capacity group (its prefill
+    dispatch) of ``caps`` [R] rows per expert; served-token positions are
+    never dropped (each decode step's group holds at most as many tokens as
+    its capacity).  Called as ``TrainDispatch`` is, on h [R, N, d]."""
+
+    def __init__(self, in_prompt, caps, cfg: dict):
+        self.in_prompt, self.caps = in_prompt, caps
+        self.n_experts, self.k = cfg["n_experts"], cfg["serve_top_k"]
+
+    def __call__(self, h, route, experts):
+        r, n = self.in_prompt.shape
+        e = self.n_experts
+        probs, idx, gw = route(h)
+        rk = ranks(idx, e, member=self.in_prompt)
+        keep = ~(self.in_prompt[..., None]
+                 & (rk >= self.caps[:, None, None]))
+        wd = combine_weights(idx, gw, keep, e)
+        y = experts(h.reshape(r * n, -1), wd.reshape(r * n, e))
+        return y.reshape(r, n, -1), probs, idx, keep
+
+
+def serve_logits(block, w, tokens, prompt_len, caps, query, cfg,
+                 precision="f32"):
+    """Next-token logits of a batch of requests.
 
     tokens [R, N]: each request's prompt followed by its served tokens
     (right-padded); prompt_len [R]; caps [R]: the expert capacity of the
-    request's prefill; query [R, Q]: positions whose logits are wanted.
-    Prompt positions form one capacity group per request (the prefill
-    dispatch); served-token positions are never dropped (each decode
-    step's group holds at most as many tokens as its capacity)."""
+    request's prefill; query [R, Q]: positions whose logits are wanted."""
     r, n = tokens.shape
-    e, k = cfg["n_experts"], cfg["serve_top_k"]
     x = w["embed"].astype(F32)[tokens]
     in_prompt = jnp.arange(n)[None, :] < prompt_len[:, None]
-
-    def layer(x, lw):
-        x = attention(x, lw, cfg, precision)
-        h = rms_norm(x, lw["ln2"])
-        _, idx, gw = route(h, lw["router"], k, precision)
-        rk = ranks(idx, e, member=in_prompt)
-        keep = ~(in_prompt[..., None] & (rk >= caps[:, None, None]))
-        wd = jnp.sum(jax.nn.one_hot(idx, e, dtype=F32)
-                     * (gw * keep)[..., None], axis=2)
-        y = experts(h.reshape(r * n, -1), wd.reshape(r * n, e), lw,
-                    precision)
-        return x + y.reshape(r, n, -1), None
-
-    x, _ = jax.lax.scan(layer, x, _layers(w))
+    x = block.serve_layers(x, w, cfg, ServeDispatch(in_prompt, caps, cfg),
+                           precision)
     xq = jnp.take_along_axis(x, query[..., None], axis=1)
     xq = rms_norm(xq, w["final_norm"])
     return mm("rqd,dv->rqv", xq, w["lm_head"], precision)
 
 
-@partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _serve_block(w, tokens, prompt_len, caps, query, served, *, cfg_items,
-                 control):
-    cfg = dict(cfg_items)
-    ref = serve_logits(w, tokens, prompt_len, caps, query, cfg)
+@partial(jax.jit, static_argnames=("block", "cfg_json", "control"))
+def _batch_gaps(w, tokens, prompt_len, caps, query, served, *, block,
+                cfg_json, control):
+    cfg = json.loads(cfg_json)
+    ref = serve_logits(block, w, tokens, prompt_len, caps, query, cfg)
     best = jnp.max(ref, -1)
     pick = served
     if control:
-        low = serve_logits(w, tokens, prompt_len, caps, query, cfg, "fp8")
+        low = serve_logits(block, w, tokens, prompt_len, caps, query, cfg,
+                           "fp8")
         pick = jnp.argmax(low, -1)
     return best - jnp.take_along_axis(ref, pick[..., None], -1)[..., 0]
 
 
-def serve_gaps(w, requests, cfg, *, control: bool = False, block: int = 8):
+def serve_gaps(block, w, requests, cfg, *, control: bool = False,
+               batch: int = 8):
     """For each request (prompt int array, served int array), the gap by
     which each served token's reference logit lies below the reference's
-    best at that position.  ``control=True`` replaces the served tokens by
-    the first choices of the fp8 reference."""
-    keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "n_experts",
-            "serve_top_k", "capacity_factor")
-    cfg_items = tuple((k_, cfg[k_]) for k_ in keys)
+    best at that position, ``batch`` requests a call.  ``control=True``
+    replaces the served tokens by the first choices of the fp8
+    reference."""
+    cfg_json = json.dumps(cfg, sort_keys=True)
     n = max(len(p) + len(s) - 1 for p, s in requests)
     n = -(-n // 8) * 8
     q = max(len(s) for _, s in requests)
     out = []
-    for i in range(0, len(requests), block):
-        part = requests[i:i + block]
-        tok = np.zeros((block, n), np.int32)
-        plen = np.ones((block,), np.int32)
-        caps = np.full((block,), 8, np.int32)
-        query = np.zeros((block, q), np.int32)
-        served = np.zeros((block, q), np.int32)
+    for i in range(0, len(requests), batch):
+        part = requests[i:i + batch]
+        tok = np.zeros((batch, n), np.int32)
+        plen = np.ones((batch,), np.int32)
+        caps = np.full((batch,), 8, np.int32)
+        query = np.zeros((batch, q), np.int32)
+        served = np.zeros((batch, q), np.int32)
         for j, (p, s) in enumerate(part):
             seq = np.concatenate([p, s[:-1]])
             tok[j, :len(seq)] = seq
@@ -354,8 +331,9 @@ def serve_gaps(w, requests, cfg, *, control: bool = False, block: int = 8):
                                cfg["capacity_factor"])
             query[j, :len(s)] = len(p) - 1 + np.arange(len(s))
             served[j, :len(s)] = s
-        gaps = np.asarray(_serve_block(w, tok, plen, caps, query, served,
-                                       cfg_items=cfg_items, control=control))
+        gaps = np.asarray(_batch_gaps(w, tok, plen, caps, query, served,
+                                      block=block, cfg_json=cfg_json,
+                                      control=control))
         for j, (_, s) in enumerate(part):
             out.append(gaps[j, :len(s)])
     return out

@@ -5,11 +5,13 @@
 
 Everything is found by name from ``BENCHMARK.json``: the cell names its
 configuration (``bench/configs/<config>.json``) and its traffic mix
-(``bench/traffic/<mix>.json``); the mix names its driver
-(``bench/drivers/<driver>.py``); each per-layer metric is read by
-``bench/metrics/<metric>.py``; the limits of the correctness comparison are
-in ``bench/limits/<cell>.json``.  Adding a configuration, a mix, a metric
-or a cell is adding files and entries.
+(``bench/traffic/<mix>.json``); the configuration names its block
+(``bench/blocks/<block>.py``: the layer equations, weight layout and
+counts); the mix names its driver (``bench/drivers/<driver>.py``); each
+per-layer metric is read by ``bench/metrics/<metric>.py``; the limits of
+the correctness comparison are in ``bench/limits/<cell>.json``.  Adding an
+architecture, a configuration, a mix, a metric or a cell is adding files
+and entries.
 
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` a device trace of the window is taken and the metrics are the
@@ -104,6 +106,11 @@ def main(argv=None) -> int:
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     sys.path.insert(0, str(ROOT / "src"))
     sys.path.insert(0, str(ROOT))
+    from bench import blocks
+    try:
+        block = blocks.named(cfg)
+    except ValueError as e:
+        return fail(str(e))
     import jax
     jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -119,8 +126,9 @@ def main(argv=None) -> int:
     from bench.drivers.common import RunSpec
     from bench.flops import peaks
     driver = importlib.import_module(f"bench.drivers.{mix['driver']}")
-    spec = RunSpec(cell=cell, cfg=cfg, mix=mix, limits=limits, seed=args.seed,
-                   seconds=args.seconds, trace=bool(args.trace),
+    spec = RunSpec(cell=cell, cfg=cfg, block=block, mix=mix, limits=limits,
+                   seed=args.seed, seconds=args.seconds,
+                   trace=bool(args.trace),
                    devices=devices[:cell["chips"]],
                    peak=peaks(devices[0].device_kind),
                    process_age=process_age_s)

@@ -20,13 +20,14 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from bench import reference, traffic_gen, weights  # noqa: E402
+from bench import blocks, reference, traffic_gen, weights  # noqa: E402
 from bench.drivers import serve, train  # noqa: E402
 from test_bench_rehearsal import (SERVE_MIX, TRAIN_MIX, result_line,  # noqa: E402
                                   spec_for)
 
 # small, but wide enough that rounding to fp8 shows in the numbers
-SMALL = {"name": "small", "program_config": "gpt2-moe", "n_layers": 2,
+SMALL = {"name": "small", "program_config": "gpt2-moe", "block": "moe_gelu",
+         "n_layers": 2,
          "d_model": 256, "n_heads": 4, "n_kv_heads": 4, "d_ff": 512,
          "vocab_size": 2048, "ffn_type": "gelu", "n_experts": 4, "top_k": 2,
          "serve_top_k": 1, "capacity_factor": 1.25, "aux_loss_weight": 0.01,
@@ -51,9 +52,12 @@ def test_train_control_fails():
     stream = traffic_gen.LMStream(mix, SMALL["vocab_size"], 11)
     batches = [stream.batch(s) for s in range(train.CHECK_STEPS)]
 
+    block = blocks.named(SMALL)
+
     def readings(**kw):
-        w0 = weights.make(SMALL, 11, jnp.float32)
-        return reference.train_readings(w0, batches, SMALL, oc, ep=1, **kw)
+        w0 = weights.make(block, SMALL, 11, jnp.float32)
+        return reference.train_readings(block, w0, batches, SMALL, oc, ep=1,
+                                        **kw)
     ref = readings()
     ctrl = train.compare(readings(precision="fp8"), ref)
     assert not correct(ctrl, lim), ctrl

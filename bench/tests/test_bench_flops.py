@@ -11,10 +11,11 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import flops  # noqa: E402
+from bench import blocks, flops  # noqa: E402
 
 GPT2 = json.loads((ROOT / "bench/configs/gpt2-moe.json").read_text())
 TXL = json.loads((ROOT / "bench/configs/transformer-xl-moe.json").read_text())
+BLOCK = blocks.named(GPT2)
 
 
 def test_expert_ffn_counts_at_gpt2_moe_width():
@@ -37,11 +38,12 @@ def test_dispatch_combine_counts_at_gpt2_moe_width():
 def test_mfu_flops_per_token():
     # gpt2-moe, 12 layers, top-2, seq 512: 6 x 180,302,592 weights met per
     # token + 6 x 12 x 512 x 768 of causal attention
-    assert flops.train_flops_per_token(GPT2, 512) == 1_110_127_104
+    assert flops.train_flops_per_token(BLOCK, GPT2, 512) == 1_110_127_104
     # transformer-xl-moe at the cell's 3 layers
     assert TXL["n_layers"] == 3
-    assert flops.train_flops_per_token(TXL, 512) == 583_827_456
-    assert flops.serve_decode_flops(GPT2, 0) == pytest.approx(
+    assert blocks.named(TXL) is BLOCK
+    assert flops.train_flops_per_token(BLOCK, TXL, 512) == 583_827_456
+    assert flops.serve_decode_flops(BLOCK, GPT2, 0) == pytest.approx(
         2 * (12 * (4 * 768 ** 2 + 768 * 16 + 2 * 768 * 3072))
         + 4 * 12 * 768 + 2 * 768 * 50257)
 

@@ -18,12 +18,13 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import run as bench_run  # noqa: E402
+from bench import blocks, run as bench_run  # noqa: E402
 from bench.drivers import serve, train  # noqa: E402
 from bench.drivers.common import RunSpec  # noqa: E402
 from bench.flops import peaks  # noqa: E402
 
-TINY = {"name": "tiny", "program_config": "gpt2-moe", "n_layers": 2,
+TINY = {"name": "tiny", "program_config": "gpt2-moe", "block": "moe_gelu",
+        "n_layers": 2,
         "d_model": 64, "n_heads": 4, "n_kv_heads": 4, "d_ff": 128,
         "vocab_size": 512, "ffn_type": "gelu", "n_experts": 4, "top_k": 2,
         "serve_top_k": 1, "capacity_factor": 1.25, "aux_loss_weight": 0.01,
@@ -49,10 +50,12 @@ TRAIN_MIX = {"driver": "train", "batch": 2, "seq": 32, "zipf_a": 1.2,
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 
 
-def spec_for(cell, cfg, mix, limits, trace, seed=2**31 + 7, seconds=1.0):
+def spec_for(cell, cfg, mix, limits, trace, seed=2**31 + 7, seconds=1.0,
+             block=None):
     # the CPU has no peaks; the v5e's let the readers' arithmetic run
-    return RunSpec(cell=cell, cfg=cfg, mix=mix, limits=limits, seed=seed,
-                   seconds=seconds, trace=trace, devices=jax.devices()[:1],
+    return RunSpec(cell=cell, cfg=cfg, block=block or blocks.named(cfg),
+                   mix=mix, limits=limits, seed=seed, seconds=seconds,
+                   trace=trace, devices=jax.devices()[:1],
                    peak=peaks("TPU v5 lite"), process_age=lambda: 1.0)
 
 

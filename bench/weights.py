@@ -19,26 +19,24 @@ def base_key(seed: int):
     return jax.random.fold_in(key, (seed >> 31) & 0x7FFFFFFF)
 
 
-def shapes(cfg: dict) -> dict:
-    """Flat weight name -> (shape, init scale or None for ones)."""
-    L, d, f, e, v = (cfg["n_layers"], cfg["d_model"], cfg["d_ff"],
-                     cfg["n_experts"], cfg["vocab_size"])
-    hq = cfg["n_heads"] * (d // cfg["n_heads"])
-    hkv = cfg["n_kv_heads"] * (d // cfg["n_heads"])
+# the frame every block sits in: token embedding, final norm, untied LM head
+def frame_shapes(cfg: dict) -> dict:
+    d, v = cfg["d_model"], cfg["vocab_size"]
     return {
         "embed": ((v, d), d ** -0.5),
         "lm_head": ((d, v), d ** -0.5),
         "final_norm": ((d,), None),
-        "ln1": ((L, d), None),
-        "ln2": ((L, d), None),
-        "wq": ((L, d, hq), d ** -0.5),
-        "wk": ((L, d, hkv), d ** -0.5),
-        "wv": ((L, d, hkv), d ** -0.5),
-        "wo": ((L, hq, d), hq ** -0.5),
-        "router": ((L, d, e), d ** -0.5),
-        "wi": ((L, e, d, f), d ** -0.5),
-        "wo_e": ((L, e, f, d), f ** -0.5),
     }
+
+
+FRAME_NAMES = {("embed",): "embed", ("lm_head",): "lm_head",
+               ("final_norm",): "final_norm"}
+
+
+def shapes(block, cfg: dict) -> dict:
+    """Flat weight name -> (shape, init scale or None for ones): the
+    frame's and the block's layers'."""
+    return {**frame_shapes(cfg), **block.shapes(cfg)}
 
 
 def _build(spec: dict, key, dtype) -> dict:
@@ -53,64 +51,61 @@ def _build(spec: dict, key, dtype) -> dict:
     return out
 
 
-def make(cfg: dict, seed: int, dtype, shardings: dict | None = None) -> dict:
+def make(block, cfg: dict, seed: int, dtype,
+         shardings: dict | None = None) -> dict:
     """Flat weights {name: array} in ``dtype``; optional per-name
     shardings place them as they are made."""
-    spec = shapes(cfg)
+    spec = shapes(block, cfg)
     return jax.jit(lambda key: _build(spec, key, dtype),
                    out_shardings=shardings)(base_key(seed))
 
 
-def make_program(cfg: dict, seed: int, dtype, struct, shardings=None):
+def make_program(block, cfg: dict, seed: int, dtype, struct, shardings=None):
     """The same weights, made directly as the program's parameter tree
     (shape of ``struct``), optionally placed by ``shardings``."""
-    spec = shapes(cfg)
-    return jax.jit(lambda key: program_tree(struct, _build(spec, key, dtype)),
+    spec = shapes(block, cfg)
+    return jax.jit(lambda key: program_tree(block, struct,
+                                            _build(spec, key, dtype)),
                    out_shardings=shardings)(base_key(seed))
 
 
-# program leaf path suffix -> flat name
-_PROGRAM_NAMES = {
-    ("embed",): "embed", ("lm_head",): "lm_head",
-    ("final_norm",): "final_norm", ("ln1",): "ln1", ("ln2",): "ln2",
-    ("attn", "wq"): "wq", ("attn", "wk"): "wk", ("attn", "wv"): "wv",
-    ("attn", "wo"): "wo", ("moe", "router"): "router",
-    ("moe", "wi"): "wi", ("moe", "wo"): "wo_e",
-}
-
-
-def _leaf_name(path) -> str:
+def _leaf_name(block, path) -> str:
+    """The flat name of a program leaf: the longest suffix of its path
+    that the frame or the block names."""
+    names = {**FRAME_NAMES, **block.PROGRAM_NAMES}
     fields = tuple(getattr(p, "name", getattr(p, "key", str(p)))
                    for p in path)
-    for n in (2, 1):
-        if fields[-n:] in _PROGRAM_NAMES:
-            return _PROGRAM_NAMES[fields[-n:]]
+    for n in range(len(fields), 0, -1):
+        if fields[-n:] in names:
+            return names[fields[-n:]]
     raise KeyError(f"no benchmark weight for program leaf {fields}")
 
 
-def program_tree(struct, flat: dict):
+def program_tree(block, struct, flat: dict):
     """The program's parameter tree (shape of ``struct``) filled from the
     flat weights; every flat weight is used exactly once."""
-    used = set()
+    used = []
 
     def fill(path, leaf):
-        name = _leaf_name(path)
-        used.add(name)
+        name = _leaf_name(block, path)
+        used.append(name)
         arr = flat[name]
         if int(np.prod(leaf.shape)) != int(np.prod(arr.shape)):
             raise ValueError(f"{name}: program {leaf.shape} vs {arr.shape}")
         return arr.reshape(leaf.shape)
 
     tree = jax.tree_util.tree_map_with_path(fill, struct)
-    if used != set(flat):
-        raise ValueError(f"weights not used by the program: "
-                         f"{sorted(set(flat) - used)}")
+    twice = sorted({n for n in used if used.count(n) > 1})
+    unused = sorted(set(flat) - set(used))
+    if twice or unused:
+        raise ValueError(f"flat weights the program uses twice: {twice}; "
+                         f"not at all: {unused}")
     return tree
 
 
-def named_leaves(tree) -> dict:
+def named_leaves(block, tree) -> dict:
     """{flat name: program leaf} for a program tree (params or moments)."""
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        out[_leaf_name(path)] = leaf
+        out[_leaf_name(block, path)] = leaf
     return out
